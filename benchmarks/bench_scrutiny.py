@@ -37,8 +37,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import shutil
-import tempfile
 import time
 
 import jax
@@ -59,7 +57,6 @@ def run(out=print, quick: bool = False, json_path: str | None = None,
         trace_path: str | None = None):
     from repro import obs as obs_mod
     from repro.core import DeviceReport, ScrutinyConfig, scrutinize
-    from repro.launch.compile_cache import enable_persistent_cache
 
     # the whole bench runs with tracing on: scrutinize() itself emits
     # prepass/sweep spans and feeds scrutiny.sweep_s / scrutiny.d2h_bytes,
@@ -68,11 +65,6 @@ def run(out=print, quick: bool = False, json_path: str | None = None,
     obs_mod.reset()
     obs_mod.enable()
     reg = obs_mod.get_obs().registry
-
-    # persistent compilation cache, armed on a fresh dir so the first
-    # compile below is a true cold measurement that *populates* it
-    cache_dir = tempfile.mkdtemp(prefix="repro_jit_cache_")
-    enable_persistent_cache(cache_dir)
 
     n = 1 << (20 if quick else 24)          # 1M / 16.8M elements in "w"
     crit = 0.148                             # paper BT(u) critical structure
@@ -131,30 +123,6 @@ def run(out=print, quick: bool = False, json_path: str | None = None,
         reg.histogram(f"bench.sweep.device_s.p{probes}").observe(dev_s)
         reg.histogram(f"bench.sweep.host_s.p{probes}").observe(host_s)
         reg.gauge(f"bench.sweep.compile_s.p{probes}").set(compile_s)
-    # --- persistent compilation cache: cold vs warm compile --------------
-    # clearing the in-process executable cache forces the next compile to
-    # be served from the on-disk persistent cache — the *relaunch* regime
-    # (new process, same program), where the sweep's multi-second XLA
-    # compile is the dominant restart cost
-    cold_s = results["probes"]["8"]["device_compile_s"]
-    jax.clear_caches()
-    t0 = time.perf_counter()
-    scrutinize(fn, state, config=ScrutinyConfig(probes=8), key=key) \
-        .materialize()
-    warm_s = time.perf_counter() - t0
-    out(f"\n== persistent compilation cache (8-probe sweep) ==")
-    out(f"  cold compile {cold_s*1e3:.0f}ms -> warm (disk-cache relaunch) "
-        f"{warm_s*1e3:.0f}ms ({cold_s/max(warm_s, 1e-9):.1f}x)")
-    results["compile_cache"] = {
-        "cold_compile_s": cold_s, "warm_compile_s": warm_s,
-        "speedup": cold_s / max(warm_s, 1e-9),
-    }
-    reg.gauge("bench.compile_cache.cold_s").set(cold_s)
-    reg.gauge("bench.compile_cache.warm_s").set(warm_s)
-    # back to the durable default dir before dropping the measurement dir
-    enable_persistent_cache()
-    shutil.rmtree(cache_dir, ignore_errors=True)
-
     # --- static probe-sweep pruning (ISSUE 7) ----------------------------
     from repro.core.criticality import traced_step
 

@@ -40,7 +40,7 @@ def _recompute(r):
         r = dict(r)
         r["model_flops"] = mf
         chips = r["chips"]
-        from repro.launch.mesh import PEAK_FLOPS
+        from repro.launch.roofline import PEAK_FLOPS
         t_model = mf / (chips * PEAK_FLOPS)
         t_bound = max(r["t_compute_ms"], r["t_memory_ms"],
                       r["t_collective_ms"]) / 1e3

@@ -96,7 +96,8 @@ from repro.core.criticality import _path_str
 from repro.distributed.collective import (BarrierTimeout, Collective,
                                           get_collective, owned_ranges,
                                           process_segments)
-from repro.distributed.sharding import leading_axis_device_segments
+from repro.distributed.sharding import (device_segments,
+                                        leading_axis_device_segments)
 from repro.kernels.mask_pack import ops as mask_ops
 
 
@@ -254,6 +255,18 @@ class _AliveToken:
                 pass
 
 
+def _segment_pieces(segs, flo: int, fhi: int, row: int):
+    """Split the flat range ``[flo, fhi)`` of a leaf over its per-device
+    row ranges ``segs`` (``device_segments``): ``[(lo, hi, flat_piece)]``
+    in order, each piece on one device."""
+    out = []
+    for s, e, data in segs:
+        lo, hi = max(flo, s * row), min(fhi, e * row)
+        if lo < hi:
+            out.append((lo, hi, jnp.ravel(data)[lo - s * row:hi - s * row]))
+    return out
+
+
 class _CoordSnapshot:
     """One coordinated save's frozen view of this host's owned segments
     (mirrors ``manager._SaveSnapshot`` at segment granularity).
@@ -293,6 +306,7 @@ class _CoordSnapshot:
         self.jobs_left = 0
         self.fused_levels: List[Any] = []   # levels this host leads
         layout = []
+        retiled: Dict[str, Any] = {}   # leaf -> its per-device row ranges
         for name, leaf, sh in mgr._flat_state(state)[0]:
             shape = tuple(getattr(leaf, "shape", ()))
             dtype = (str(leaf.dtype) if hasattr(leaf, "dtype")
@@ -316,26 +330,41 @@ class _CoordSnapshot:
                        "nbytes": total * itemsize}
                 is_dev = isinstance(leaf, jax.Array) and seg_n > 0
                 use_xla = self.engine == "xla" and is_dev
+                to_pack = use_xla and device and mask_seg is not None
+                # a segment of a leaf on several devices is packed in one
+                # piece per device (a Pallas kernel runs on one device's
+                # data)
+                split = (to_pack and not distributed
+                         and len(leaf.devices()) > 1)
                 if distributed and seg_n > 0:
                     flat_seg = mgr._local_flat_segment(leaf, flo, fhi, row)
-                elif use_xla:
+                elif use_xla and not split:
                     flat_seg = jnp.ravel(leaf)[flo:fhi]
                 else:
                     flat_seg = None
-                if use_xla and device and mask_seg is not None:
-                    # stage 1: group member — one compiled pack per
+                if to_pack:
+                    # stage 1: group members — one compiled pack per
                     # (device, dtype) group, payload size static
-                    key = (dtype, tuple(sorted(
-                        str(d) for d in leaf.devices())))
-                    g = self._groups.setdefault(
-                        key, {"flats": [], "masks": [], "totals": [],
-                              "keys": []})
-                    g["flats"].append(flat_seg)
-                    g["masks"].append(jnp.asarray(mask_seg))
-                    g["totals"].append(total)
-                    g["keys"].append((name, int(flo), int(fhi)))
+                    if split:
+                        if name not in retiled:
+                            retiled[name] = device_segments(leaf)[0]
+                        pieces = _segment_pieces(retiled[name], flo, fhi,
+                                                 row)
+                    else:
+                        pieces = [(int(flo), int(fhi), flat_seg)]
                     seg["kind"] = "group"
-                    seg["key"] = key
+                    seg["parts"] = []
+                    for lo, hi, piece in pieces:
+                        key = (dtype, str(next(iter(piece.devices()))))
+                        g = self._groups.setdefault(
+                            key, {"flats": [], "masks": [], "totals": [],
+                                  "keys": []})
+                        m = mask_seg[lo - flo:hi - flo]
+                        g["flats"].append(piece)
+                        g["masks"].append(jnp.asarray(m))
+                        g["totals"].append(int(m.sum()))
+                        g["keys"].append((name, lo, hi))
+                        seg["parts"].append((key, (name, lo, hi)))
                 elif flat_seg is not None:
                     # pinned device slice, fetched (xla) or viewed (host
                     # backend of a distributed leaf) on the writer thread
@@ -383,9 +412,13 @@ class _CoordSnapshot:
                 itemsize = np.dtype(seg["dtype"]).itemsize
                 mask_seg = seg["mask"]
                 if seg["kind"] == "group":
-                    g = self._groups[seg["key"]]
-                    lo, hi = g["ranges"][(name, flo, fhi)]
-                    u8 = group_host[seg["key"]][lo * itemsize:hi * itemsize]
+                    parts = []
+                    for key, k in seg["parts"]:
+                        lo, hi = self._groups[key]["ranges"][k]
+                        parts.append(
+                            group_host[key][lo * itemsize:hi * itemsize])
+                    u8 = parts[0] if len(parts) == 1 else \
+                        np.concatenate(parts)
                 elif seg["kind"] == "dev":
                     flat_seg = self._pinned[(name, flo, fhi)]
                     if self.engine == "xla" and mask_seg is None:
@@ -623,6 +656,12 @@ class CoordinatedCheckpointManager:
                     first = e
         if first is not None:
             raise first
+        with self._lock:
+            live = self._live_save_stats
+        if futs and live is not None:
+            # a fresh freeze of the drained save: distinct from the
+            # dispatch-time snapshot however early the jobs landed
+            self.last_save_stats = self.obs.registry.publish("save", live)
         return self.last_save_stats
 
     # --- scrutiny --------------------------------------------------------
@@ -681,6 +720,7 @@ class CoordinatedCheckpointManager:
         futs: List[cf.Future] = []
         due = [lv for lv in self.levels if step % lv.interval == 0]
         snap.jobs_left = len(due)
+        jobs = []
         for lv in due:
             # double buffer: drain the previous in-flight save for this
             # level on the caller thread (its error propagates here, once)
@@ -690,22 +730,23 @@ class CoordinatedCheckpointManager:
             self._seq += 1
             seq = self._seq
             tag = f"q{seq}.L{self.levels.index(lv)}"
-            plan = self._plan_level(lv, step, report, snap)
+            jobs.append((lv, seq, tag,
+                         self._plan_level(lv, step, report, snap)))
+        stats["blocked_s"] = time.perf_counter() - t0
+        # dispatch snapshot: an immutable view of what the caller blocked
+        # for.  Published before any level job exists, so the finalized
+        # snapshot (writer phase timings) always replaces it when the jobs
+        # drain (wait() returns that one).
+        with self._lock:
+            self._live_save_stats = stats
+        self.last_save_stats = self.obs.registry.publish("save", stats)
+        self.obs.registry.counter("save.dispatches").inc()
+        for lv, seq, tag, plan in jobs:
             fut = self._pool.submit(self._run_level_job, lv, step, seq,
                                     tag, snap, plan, stats)
             self._inflight[lv.directory] = fut
             fired.append(lv)
             futs.append(fut)
-        with snap.stats_lock:
-            stats["blocked_s"] = time.perf_counter() - t0
-        # dispatch snapshot: an immutable view of what the caller blocked
-        # for; the finalized snapshot (writer phase timings) replaces it
-        # when the level jobs drain (wait() returns that one)
-        with self._lock:
-            self._live_save_stats = stats
-        with snap.stats_lock:
-            self.last_save_stats = self.obs.registry.publish("save", stats)
-        self.obs.registry.counter("save.dispatches").inc()
         if not due:
             snap.obs_handle.finish()
         if block:

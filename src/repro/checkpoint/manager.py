@@ -68,8 +68,10 @@ asynchronous save engine**.
   dependencies; stale ``.tmp_step_*`` dirs from crashed writers are swept.
 
 ``last_save_stats`` adds pipeline observability: ``blocked_s`` (how long
-``save()`` held the caller), ``stages`` (per-stage seconds), and
-``engine``.  Stats are **immutable snapshots** published through the
+``save()`` held the caller), ``stages`` (per-stage seconds),
+``engine``, and ``retiled_leaves``/``retiled_bytes`` (sharded leaves
+whose layout splits a non-leading axis, re-tiled into per-device row
+ranges before the pack kernel saw them).  Stats are **immutable snapshots** published through the
 ``repro.obs`` metrics registry: an early snapshot at dispatch time (with
 whatever stages have run synchronously) and a finalized one — also
 returned by ``wait()`` — when the level jobs drain.  Writer threads only
@@ -115,8 +117,7 @@ from repro.checkpoint.store import (StreamLeaf, chain_steps,
 from repro.core.criticality import (CriticalityReport, DeviceReport,
                                     _path_str)
 from repro.core.policy import PrecisionPolicy
-from repro.distributed.sharding import (leaf_segments,
-                                        pack_sharded_payload_device,
+from repro.distributed.sharding import (pack_sharded_payload_device,
                                         scatter_sharded_payload)
 from repro.kernels.mask_pack import ops as mask_ops
 
@@ -241,6 +242,7 @@ class _SaveSnapshot:
         self._flats: Dict[str, Any] = {}          # xla: flat device leaves
         self._payload_dev: Dict[str, Any] = {}    # xla: sharded leaf payloads
         self._groups: Dict[Any, Dict[str, Any]] = {}
+        self.retiled_bytes: Dict[str, int] = {}   # leaves re-tiled to pack
         self._pin_and_dispatch()
         # lazy job-side state
         self._lock = threading.Lock()
@@ -287,13 +289,17 @@ class _SaveSnapshot:
             if kind == "dev_raw":
                 self._flats[name] = jnp.ravel(leaf)
                 continue
-            if leaf_segments(leaf) is not None:
-                # sharded scrutinized leaf: per-shard on-device pack; the
+            if leaf.is_fully_addressable and len(leaf.devices()) > 1:
+                # sharded scrutinized leaf: per-device on-device pack (a
+                # Pallas kernel runs on one device's data; layouts off the
+                # leading axis are re-tiled into row ranges first); the
                 # (critical-fraction-sized) payload stays device-resident
                 # and streams through stage 2 like a group payload
-                payload, _counts, _ = pack_sharded_payload_device(
+                payload, _counts, _, retiled = pack_sharded_payload_device(
                     leaf, rep.device_mask(), **self.mgr._pack_opts)
                 self._payload_dev[name] = payload
+                if retiled:
+                    self.retiled_bytes[name] = int(leaf.nbytes)
                 continue
             key = (str(leaf.dtype),
                    tuple(sorted(str(d) for d in leaf.devices()))
@@ -654,7 +660,8 @@ class CheckpointManager:
         """Block until every in-flight write lands.  Clears the in-flight
         table first, so each writer exception propagates exactly once.
         Returns the finalized ``last_save_stats`` snapshot (the level jobs
-        republish it as they drain), or None if nothing was saved."""
+        republish it as they drain, and a wait that drained jobs publishes
+        it once more), or None if nothing was saved."""
         futs = list(self._inflight.values())
         self._inflight.clear()
         errs = []
@@ -669,6 +676,12 @@ class CheckpointManager:
             f.result()          # best-effort writes never raise
         if errs:
             raise errs[0]
+        with self._lock:
+            live = self._live_save_stats
+        if futs and live is not None:
+            # a fresh freeze of the drained save: distinct from the
+            # dispatch-time snapshot however early the jobs landed
+            self.last_save_stats = self.obs.registry.publish("save", live)
         return self.last_save_stats
 
     # --- save ------------------------------------------------------------
@@ -736,6 +749,8 @@ class CheckpointManager:
             "full_bytes": int(snap.full_bytes),
             "packed_leaves": sum(1 for *_, k in snap.items
                                  if k == "dev_payload"),
+            "retiled_leaves": len(snap.retiled_bytes),
+            "retiled_bytes": sum(snap.retiled_bytes.values()),
             "levels": level_stats,
             "stages": {"snapshot_s": time.perf_counter() - t1},
             "blocked_s": 0.0,
@@ -802,21 +817,19 @@ class CheckpointManager:
 
         snap.jobs_left = len(plans)
         snap.fired_levels = [lv for lv, _ in plans]
+        stats["blocked_s"] = time.perf_counter() - t0
+        # dispatch-time snapshot: immutable, safe to read before wait().
+        # Published before any level job exists, so the last job to drain
+        # always republishes the finalized snapshot over it.
+        with self._lock:
+            self._live_save_stats = stats
+        self.last_save_stats = self.obs.registry.publish("save", stats)
+        self.obs.registry.counter("save.dispatches").inc()
         futs = []
         for lv, write in plans:
             fut = self._pool.submit(self._run_job, write, snap, step)
             self._inflight[lv.directory] = fut
             futs.append(fut)
-        stats["blocked_s"] = time.perf_counter() - t0
-        # dispatch-time snapshot: immutable, safe to read before wait();
-        # the level jobs republish a finalized snapshot as they drain.
-        # Writers mutate only under snap._stats_lock, so the deep-freeze
-        # below never iterates a dict another thread is resizing.
-        with self._lock:
-            self._live_save_stats = stats
-        with snap._stats_lock:
-            self.last_save_stats = self.obs.registry.publish("save", stats)
-        self.obs.registry.counter("save.dispatches").inc()
         self.obs.registry.counter("save.d2h_bytes").inc(stats["d2h_bytes"])
         if not plans:
             snap.obs_handle.finish()

@@ -218,7 +218,7 @@ def scrutiny_words_shardings(state, shardings) -> Dict[str, Any]:
 
     For every leaf whose sharding tiles only the leading axis into
     byte-aligned flat segments (the DP/FSDP parameter layouts that
-    ``pack_sharded_payload`` packs per shard), the flat word array
+    ``pack_sharded_payload_device`` packs per shard), the flat word array
     ``(ceil(n/8),)`` can carry the same leading-axis spec — per-shard mask
     words then land on the device whose shard they describe.  Leaves with
     any other layout map to ``None`` (words stay wherever the sweep puts
@@ -285,54 +285,28 @@ def _leading_axis_shards(leaf) -> Optional[List[Tuple[int, int, Any]]]:
     return [(s, stops[s], uniq[s]) for s in starts]
 
 
-def leaf_segments(leaf) -> Optional[List[Tuple[int, int, Any]]]:
-    """Public wrapper for the pipelined save engine: the leading-axis
-    [(start, stop, shard_data)] tiling of a multi-shard addressable leaf,
-    or ``None`` for single-device / unsupported layouts (the caller then
-    treats the leaf as one flat segment)."""
-    if getattr(leaf, "is_fully_addressable", True) and \
-            len(getattr(leaf, "addressable_shards", ()) or ()) > 1:
-        return _leading_axis_shards(leaf)
-    return None
+def device_segments(leaf) -> Tuple[List[Tuple[int, int, Any]], bool]:
+    """Row ranges of a fully addressable leaf that spans several devices,
+    each held by one device: ``([(row_start, row_stop, data)], retiled)``
+    with the ranges in order, exactly covering axis 0.
 
-
-def pack_sharded_payload(leaf, mask: np.ndarray, *, block: int = BLOCK,
-                         use_kernel: Optional[bool] = None,
-                         interpret: bool = False):
-    """Pack a (possibly sharded) device array's critical elements, moving
-    only packed bytes device→host.
-
-    When the leaf is sharded along its leading axis (DP/FSDP parameter
-    layouts), each shard is compacted **on its own device** and only its
-    critical prefix crosses D2H — no cross-device gather of the full leaf
-    ever happens.  Any other layout falls back to a global device-side pack
-    (XLA handles the collective; the host still only receives packed bytes).
-
-    Returns ``(payload, counts, d2h_bytes)`` with ``payload`` in global flat
-    (C) order — identical bytes to the host path.
-
-    ``mask`` may be a host bool array or a resident device mask (from a
-    ``DeviceReport``) — the latter never round-trips through the host.
-    """
-    mask = _as_flat_mask(mask)
-    segs = None
-    if getattr(leaf, "is_fully_addressable", True) and \
-            len(getattr(leaf, "addressable_shards", ()) or ()) > 1:
-        segs = _leading_axis_shards(leaf)
-    if segs is None:
-        return mask_ops.pack_critical(jnp.ravel(leaf), mask, block=block,
-                                      use_kernel=use_kernel,
-                                      interpret=interpret)
-    row = int(np.prod(leaf.shape[1:])) if leaf.ndim > 1 else 1
-    payloads, counts, moved = [], [], 0
-    for s, e, data in segs:
-        p, c, m = mask_ops.pack_critical(
-            jnp.ravel(data), _mask_segment(mask, s * row, e * row, data),
-            block=block, use_kernel=use_kernel, interpret=interpret)
-        payloads.append(p)
-        counts.append(c)
-        moved += m
-    return (np.concatenate(payloads), np.concatenate(counts), moved)
+    A leading-axis layout (DP/FSDP) answers with its own shards and moves
+    nothing.  Any other layout (a model-axis split, a 2-D split) is
+    re-tiled (``retiled=True``): the rows are split evenly over the leaf's
+    devices and each range is moved onto its device, so every device ends
+    up with about ``1/n`` of the leaf and none holds all of it.  A Pallas
+    kernel runs on one device's data, so this is how a sharded leaf
+    reaches the pack kernel."""
+    segs = _leading_axis_shards(leaf)
+    if segs is not None:
+        return segs, False
+    if leaf.ndim == 0:          # a 0-d leaf on several devices is replicated
+        return [(0, 1, leaf.addressable_shards[0].data)], False
+    devs = sorted(leaf.devices(), key=lambda d: d.id)
+    k = min(len(devs), leaf.shape[0])
+    cuts = [leaf.shape[0] * i // k for i in range(k + 1)]
+    return ([(a, b, jax.device_put(leaf[a:b], d))
+             for a, b, d in zip(cuts, cuts[1:], devs)], True)
 
 
 def _pack_payload_device(flat, mask, *, block: int = BLOCK,
@@ -352,30 +326,29 @@ def _pack_payload_device(flat, mask, *, block: int = BLOCK,
     return payload, counts_h, counts_h.nbytes
 
 
-def pack_sharded_payload_device(leaf, mask: np.ndarray, *, block: int = BLOCK,
+def pack_sharded_payload_device(leaf, mask, *, block: int = BLOCK,
                                 use_kernel: Optional[bool] = None,
                                 interpret: bool = False):
-    """Device-resident variant of :func:`pack_sharded_payload` for the
-    differential save path: each leading-axis shard is compacted on its own
-    device, then the (already critical-fraction-sized) payloads are
+    """Pack a (possibly sharded) device leaf's critical elements, keeping
+    the payload on device.  A leaf on several devices is packed **per
+    device** over :func:`device_segments` (no device ever gathers the full
+    leaf), then the (already critical-fraction-sized) payloads are
     concatenated into one device array that stays resident as the delta
-    base — only the per-tile counts cross D2H.
+    base — only the per-tile counts cross D2H.  The payload is in global
+    flat (C) order, identical bytes to the host path.
 
-    Returns ``(payload_dev, counts_h, d2h_bytes)``.  Note the concatenation
-    gathers the *packed* payloads onto one device; cross-device traffic is
-    ∝ the critical fraction, never the full leaf.  Like
-    :func:`pack_sharded_payload`, a resident device mask is consumed
-    without any host round-trip.
+    Returns ``(payload_dev, counts_h, d2h_bytes, retiled)``; ``retiled``
+    says whether the leaf's layout had to be re-tiled into row ranges.
+    The concatenation gathers the *packed* payloads onto one device, so
+    cross-device traffic there is ∝ the critical fraction.  A resident
+    device mask is consumed without any host round-trip.
     """
     mask = _as_flat_mask(mask)
-    segs = None
-    if getattr(leaf, "is_fully_addressable", True) and \
-            len(getattr(leaf, "addressable_shards", ()) or ()) > 1:
-        segs = _leading_axis_shards(leaf)
-    if segs is None:
+    if len(leaf.devices()) == 1 or not leaf.is_fully_addressable:
         return _pack_payload_device(jnp.ravel(leaf), mask, block=block,
                                     use_kernel=use_kernel,
-                                    interpret=interpret)
+                                    interpret=interpret) + (False,)
+    segs, retiled = device_segments(leaf)
     row = int(np.prod(leaf.shape[1:])) if leaf.ndim > 1 else 1
     payloads, counts, moved = [], [], 0
     for s, e, data in segs:
@@ -390,7 +363,8 @@ def pack_sharded_payload_device(leaf, mask: np.ndarray, *, block: int = BLOCK,
     home = payloads[0].devices()
     payloads = [p if p.devices() == home else jax.device_put(p, list(home)[0])
                 for p in payloads]
-    return jnp.concatenate(payloads), np.concatenate(counts), moved
+    return (jnp.concatenate(payloads), np.concatenate(counts), moved,
+            retiled)
 
 
 # --------------------------------------------------------------------------
@@ -442,7 +416,7 @@ def scatter_sharded_payload(payload: np.ndarray, mask: np.ndarray,
                             block: int = BLOCK,
                             use_kernel: Optional[bool] = None,
                             interpret: bool = False):
-    """Restore inverse of :func:`pack_sharded_payload`: move only the
+    """Restore inverse of :func:`pack_sharded_payload_device`: move only the
     critical ``payload`` (plus the bit-packed mask) H2D and scatter it into
     a fill-initialized device buffer via ``kernels/mask_pack``.
 

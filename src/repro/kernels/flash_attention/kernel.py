@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -109,7 +106,7 @@ def flash_attention_kernel(q, k, v, *, scale: float, causal: bool,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom l
             pltpu.VMEM((bq, Dv), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 BLOCK_T = 256
 BLOCK_R = 128
@@ -68,7 +65,7 @@ def lru_scan_kernel(a: jnp.ndarray, b: jnp.ndarray, h0: jnp.ndarray,
                                lambda g, t, nr=nr: (g // nr, t, g % nr)),
         out_shape=jax.ShapeDtypeStruct((B, T, R), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, br), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

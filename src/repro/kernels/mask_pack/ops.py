@@ -19,16 +19,23 @@ re-expands into a fill-initialized device buffer via the fused
 fraction exactly like save.
 
 ``delta_encode`` is the differential-checkpoint primitive: it compares the
-current and base payloads *as raw bytes on device* per fixed-size chunk and
+current and base payloads' raw bits on device per fixed-size chunk and
 moves only changed chunks D2H, so successive saves of a slowly-changing
 state cost ∝ changed bytes (disk and PCIe both).
 
-Dtype handling: the MXU permutation-matmul kernel computes in float32, which
-is exact for f32/bf16/f16 payloads; integer and f64 leaves are routed to the
-pure-jnp oracle (exact in the native dtype) regardless of backend.  Arbitrary
-leaf sizes are handled by padding to the BLOCK grid here — the raw kernels
-require ``N % block == 0``.  The delta kernel compares bytes (no matmul), so
-it is exact for every dtype.
+Dtype handling: every path works on the leaf's raw bits (float leaves
+are viewed as same-width unsigned ints), so packs, restores and deltas are
+bit-exact for every value, NaN payloads and -0.0 included.  The kernels
+move 32-bit words (16-bit dtypes zero-extended) and take every 2- and
+4-byte dtype; 1- and 8-byte leaves are routed to the pure-jnp oracle
+regardless of backend.  Arbitrary leaf sizes are handled by padding to the
+BLOCK grid here — the raw kernels require ``N % block == 0``.
+
+Devices: a Pallas kernel runs on one device's data and cannot be
+partitioned.  ``threshold_bitpack`` and ``delta_encode`` run it once per
+device on operands that span several (:func:`_per_device`); the pack
+entry points take one device's data, and the save pipelines hand them
+per-device pieces (``distributed.sharding.device_segments``).
 """
 
 from __future__ import annotations
@@ -38,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.mask_pack.kernel import (BITPACK_BLOCK, BLOCK,
                                             bitpack_blocks_kernel,
@@ -50,12 +58,8 @@ from repro.kernels.mask_pack.ref import (bitpack_blocks_ref, delta_blocks_ref,
                                          scatter_blocks_ref,
                                          unpack_blocks_ref)
 
-# dtypes the MXU kernel packs exactly (everything else → jnp oracle).
-_KERNEL_EXACT = (jnp.float32, jnp.bfloat16, jnp.float16)
-
-# Tiles per kernel grid step (superblock batching; see
-# kernel.pack_blocks_kernel).  ops.pack pads the tile count to a multiple.
-PACK_ROWS = 8
+# itemsizes the word-moving kernels pack (everything else → jnp oracle).
+_KERNEL_ITEMSIZES = (2, 4)
 
 # Chunk granularity of the delta format, in bytes — a multiple of every
 # leaf itemsize so chunks never split an element.  Single source of truth:
@@ -69,34 +73,77 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _spans_devices(x) -> bool:
+    return (isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer)
+            and len(x.devices()) > 1)
+
+
+def _per_device(kernel, xs, *, tile: int, pads):
+    """Run ``kernel`` (a one-device kernel call on 1-D operands whose
+    outputs are per-tile along axis 0) on each device's piece of ``xs``,
+    operands that span several devices.  A Pallas kernel cannot be
+    partitioned, so the operands are laid flat over their devices in
+    tile-aligned pieces (padded with ``pads``; a no-op for a layout that
+    already is one) and the kernel runs per piece under ``shard_map``:
+    each device computes on about ``1/n`` of the data, and the outputs
+    come back in global tile order, sharded the same way (the caller
+    slices off the padding tiles)."""
+    devs = sorted(set().union(*(x.devices() for x in xs)),
+                  key=lambda d: d.id)
+    n, k = xs[0].shape[0], len(devs)
+    pad = -(-n // (k * tile)) * tile * k - n
+    mesh = Mesh(np.array(devs), ("d",))
+    xs = [jax.device_put(jnp.pad(x, (0, pad), constant_values=v) if pad
+                         else x, NamedSharding(mesh, P("d")))
+          for x, v in zip(xs, pads)]
+    return per_device_fn(kernel, mesh, len(xs))(*xs)
+
+
+def per_device_fn(kernel, mesh: Mesh, nargs: int):
+    """``kernel`` over ``nargs`` 1-D operands laid out ``P("d")`` over the
+    1-D ``mesh``: one kernel call per device on its own piece."""
+    spec = P("d")
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
+                         out_specs=spec, check_vma=False)
+
+
+_UINT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
+
+
+def _raw(x: jnp.ndarray) -> jnp.ndarray:
+    """Same-width unsigned view of a float array, so pad, gather, scatter
+    and select move bits, never values: XLA:CPU widens some bf16 ops to
+    f32 and back, which rewrites NaN payloads."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return jax.lax.bitcast_convert_type(
+            x, _UINT[np.dtype(x.dtype).itemsize])
+    return x
+
+
+def _cooked(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """Inverse of :func:`_raw`."""
+    return x if x.dtype == dtype else jax.lax.bitcast_convert_type(x, dtype)
+
+
 def _use_kernel(flat: jnp.ndarray, use_kernel) -> bool:
     uk = _on_tpu() if use_kernel is None else use_kernel
-    return bool(uk) and flat.dtype in _KERNEL_EXACT
+    return bool(uk) and np.dtype(flat.dtype).itemsize in _KERNEL_ITEMSIZES
 
 
 def _pack_traced(flat: jnp.ndarray, mask: jnp.ndarray, *, block: int,
                  use_kernel, interpret: bool):
     """Trace-time pack body shared by :func:`pack` and :func:`pack_group`:
-    pads to the (superblocked) grid, dispatches kernel/oracle, and slices
-    the padding tiles back off."""
+    pads to the tile grid and dispatches kernel/oracle, on the leaf's raw
+    bits (:func:`_raw`).  Returns raw packed tiles and counts."""
+    flat = _raw(flat)
     n = flat.shape[0]
-    nb = -(-n // block)
-    if n and _use_kernel(flat, use_kernel):
-        nb_pad = -(-nb // PACK_ROWS) * PACK_ROWS
-        pad = nb_pad * block - n
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-            mask = jnp.pad(mask, (0, pad))
-        packed, counts = pack_blocks_kernel(flat, mask.astype(jnp.int8),
-                                            block=block, interpret=interpret,
-                                            rows=PACK_ROWS)
-        if nb_pad != nb:
-            packed, counts = packed[:nb], counts[:nb]
-        return packed, counts
     pad = (-n) % block
     if pad:
         flat = jnp.pad(flat, (0, pad))
         mask = jnp.pad(mask, (0, pad))
+    if n and _use_kernel(flat, use_kernel):
+        return pack_blocks_kernel(flat, mask.astype(jnp.int8), block=block,
+                                  interpret=interpret)
     return pack_blocks_ref(flat, mask, block=block)
 
 
@@ -106,8 +153,9 @@ def pack(flat: jnp.ndarray, mask: jnp.ndarray, *, block: int = BLOCK,
          use_kernel: bool | None = None, interpret: bool = False):
     """flat: (N,) any dtype; mask: (N,) bool — any N (padded to the grid).
     Returns (packed (ceil(N/block), block), counts (ceil(N/block),))."""
-    return _pack_traced(flat, mask, block=block, use_kernel=use_kernel,
-                        interpret=interpret)
+    packed, counts = _pack_traced(flat, mask, block=block,
+                                  use_kernel=use_kernel, interpret=interpret)
+    return _cooked(packed, flat.dtype), counts
 
 
 @functools.partial(jax.jit,
@@ -116,23 +164,26 @@ def unpack(packed: jnp.ndarray, mask: jnp.ndarray, *, n: int,
            block: int = BLOCK, fill: float = 0.0,
            use_kernel: bool | None = None, interpret: bool = False):
     """Inverse of :func:`pack`; returns (n,) restored flat array."""
+    dtype = packed.dtype
+    packed = _raw(packed)
     total = packed.shape[0] * packed.shape[1]
     pad = total - n
     m = jnp.pad(mask, (0, pad)) if pad else mask
-    fill = jnp.asarray(fill, packed.dtype)  # no accidental float promotion
+    fill = _raw(jnp.asarray(fill, dtype))   # no accidental float promotion
     if _use_kernel(packed, use_kernel):
         out = unpack_blocks_kernel(packed, m.astype(jnp.int8), fill=fill,
                                    interpret=interpret)
     else:
         out = unpack_blocks_ref(packed, m, fill=fill)
-    return out[:n]
+    return _cooked(out[:n], dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("total",))
 def gather_payload(packed: jnp.ndarray, counts: jnp.ndarray, *, total: int):
     """Device-side: compact the per-tile critical prefixes into one dense
     (total,) payload — the only big buffer that crosses D2H on save."""
-    return gather_payload_ref(packed, counts, total)
+    return _cooked(gather_payload_ref(_raw(packed), counts, total),
+                   packed.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("totals", "block", "use_kernel",
@@ -146,7 +197,7 @@ def _pack_group_jit(flats, masks, *, totals, block, use_kernel, interpret):
         if t:
             payloads.append(gather_payload_ref(packed, cnt, t))
     dtype = flats[0].dtype if flats else jnp.float32
-    payload = (jnp.concatenate(payloads) if payloads
+    payload = (_cooked(jnp.concatenate(payloads), dtype) if payloads
                else jnp.zeros((0,), dtype))
     cnt = (jnp.concatenate(counts) if counts
            else jnp.zeros((0,), jnp.int32))
@@ -173,26 +224,15 @@ def pack_group(flats, masks, totals, *, block: int = BLOCK,
     totals = tuple(int(t) for t in totals)
     if len(flats) != len(masks) or len(flats) != len(totals):
         raise ValueError("pack_group: flats/masks/totals length mismatch")
+    for f in flats:
+        if _spans_devices(f) and _use_kernel(f, use_kernel):
+            raise ValueError(
+                "pack_group: a leaf spans several devices; a Pallas kernel "
+                "cannot be partitioned, so pack one device's piece at a "
+                "time (distributed.sharding.device_segments)")
     return _pack_group_jit(tuple(flats), tuple(masks), totals=totals,
                            block=block, use_kernel=use_kernel,
                            interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("block",))
-def scatter_payload(payload: jnp.ndarray, counts: jnp.ndarray, *,
-                    block: int = BLOCK):
-    """Device-side inverse of :func:`gather_payload`: dense payload →
-    (nb, block) tiles with counts[i]-long prefixes (feeds ``unpack``)."""
-    nb = counts.shape[0]
-    total = payload.shape[0]
-    if total == 0:
-        return jnp.zeros((nb, block), payload.dtype)
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    idx = starts[:, None] + jnp.arange(block)[None, :]
-    valid = jnp.arange(block)[None, :] < counts[:, None]
-    vals = payload[jnp.clip(idx, 0, total - 1)]
-    return jnp.where(valid, vals, jnp.zeros((), payload.dtype))
 
 
 def pack_critical(flat: jnp.ndarray, mask, *, block: int = BLOCK,
@@ -221,13 +261,15 @@ def pack_critical(flat: jnp.ndarray, mask, *, block: int = BLOCK,
                    static_argnames=("n", "block", "use_kernel", "interpret"))
 def _mask_scatter_jit(payload, mask, fill, *, n: int, block: int,
                       use_kernel, interpret: bool):
+    dtype = payload.dtype
+    payload = _raw(payload)
     total = payload.shape[0]
     nb = -(-n // block)
     pad = nb * block - n
     m = jnp.pad(mask, (0, pad)) if pad else mask
     counts = m.reshape(nb, block).sum(axis=1).astype(jnp.int32)
     starts = jnp.cumsum(counts) - counts
-    fill = jnp.asarray(fill, payload.dtype)
+    fill = _raw(jnp.asarray(fill, dtype))
     if _use_kernel(payload, use_kernel):
         npb = total // block + 2          # every 2-block window in bounds
         pp = jnp.pad(payload, (0, npb * block - total)).reshape(npb, block)
@@ -236,7 +278,7 @@ def _mask_scatter_jit(payload, mask, fill, *, n: int, block: int,
                                     interpret=interpret)
     else:
         out = scatter_blocks_ref(payload, starts, m, fill=fill, block=block)
-    return out[:n]
+    return _cooked(out[:n], dtype)
 
 
 def mask_scatter(payload, mask, *, n: int, block: int = BLOCK,
@@ -253,10 +295,10 @@ def mask_scatter(payload, mask, *, n: int, block: int = BLOCK,
     payload = jnp.asarray(payload)
     mask = jnp.asarray(mask)
     if payload.shape[0] == 0:
+        out = jnp.full((n,), fill, payload.dtype)
         if committed:           # keep empty segments on the payload's device
-            with jax.default_device(next(iter(payload.devices()))):
-                return jnp.full((n,), fill, payload.dtype)
-        return jnp.full((n,), fill, payload.dtype)
+            out = jax.device_put(out, next(iter(payload.devices())))
+        return out
     return _mask_scatter_jit(payload, mask, fill, n=n, block=block,
                              use_kernel=use_kernel, interpret=interpret)
 
@@ -273,8 +315,6 @@ def unpack_critical(payload, counts, mask, *, n: int, block: int = BLOCK,
                         use_kernel=use_kernel, interpret=interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block", "use_kernel", "interpret"))
 def threshold_bitpack(mag: jnp.ndarray, tol=0.0, *,
                       block: int = BITPACK_BLOCK,
                       use_kernel: bool | None = None,
@@ -293,14 +333,29 @@ def threshold_bitpack(mag: jnp.ndarray, tol=0.0, *,
     int32 critical counts ``(ceil(N/block),)`` — the only scrutiny outputs
     that ever need to cross D2H (1 bit/element + 4 B/tile summaries).
     """
+    uk = _on_tpu() if use_kernel is None else use_kernel
+    if uk and mag.dtype == jnp.float32 and _spans_devices(mag):
+        n = mag.shape[0]
+        words, counts = _per_device(
+            lambda m: bitpack_blocks_kernel(m, tol, block=block,
+                                            interpret=interpret),
+            [mag], tile=block, pads=[-jnp.inf])
+        return (words.reshape(-1)[:(n + 7) // 8],
+                counts[:-(-n // block)])
+    return _threshold_bitpack(mag, tol, block=block, use_kernel=uk,
+                              interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block", "use_kernel", "interpret"))
+def _threshold_bitpack(mag, tol, *, block: int, use_kernel, interpret: bool):
     n = mag.shape[0]
     pad = (-n) % block
     if pad:
         # -inf padding can never exceed tol, so padded bits (including the
         # tail bits of a kept byte when N % 8 != 0) stay 0.
         mag = jnp.pad(mag, (0, pad), constant_values=-jnp.inf)
-    uk = _on_tpu() if use_kernel is None else use_kernel
-    if uk and mag.dtype == jnp.float32:
+    if use_kernel and mag.dtype == jnp.float32:
         words, counts = bitpack_blocks_kernel(mag, tol, block=block,
                                               interpret=interpret)
     else:
@@ -322,47 +377,51 @@ def expand_mask_bits(bits, *, n: int):
 # Differential (delta) encode: byte-chunk diff on device
 # --------------------------------------------------------------------------
 
-def as_bytes(arr) -> jnp.ndarray:
-    """Flat uint8 view of a device array (bitcast, no host copy).  bool is
-    widened via astype (bitcast rejects it; 0/1 bytes match the host
-    representation).  Raises TypeError for dtypes bitcast can't handle
-    (complex) — callers fall back to a full-entry write."""
+def _delta_words(arr):
+    """Flat int32 words of a device array for the delta compare, and how
+    many bytes each word stands for: 4- and 8-byte dtypes are bitcast to
+    32-bit words, 1- and 2-byte dtypes widened one word per element (an
+    injective map, so equal words mean equal bytes).  Raises TypeError
+    for dtypes bitcast can't handle (complex) — callers fall back to a
+    full-entry write."""
     arr = jnp.ravel(jnp.asarray(arr))
-    if arr.dtype == jnp.uint8:
-        return arr
-    if arr.dtype == jnp.bool_:
-        return arr.astype(jnp.uint8)
-    return jax.lax.bitcast_convert_type(arr, jnp.uint8).reshape(-1)
+    size = np.dtype(arr.dtype).itemsize
+    if size >= 4:
+        return jax.lax.bitcast_convert_type(arr, jnp.int32).reshape(-1), 4
+    if arr.dtype != jnp.bool_:
+        arr = jax.lax.bitcast_convert_type(
+            arr, jnp.uint8 if size == 1 else jnp.uint16)
+    return arr.astype(jnp.int32), size
 
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "use_kernel", "interpret"))
-def _delta_flags(curr8, base8, *, chunk: int, use_kernel, interpret: bool):
-    pad = (-curr8.shape[0]) % chunk
+def _delta_flags(curr, base, *, chunk: int, use_kernel, interpret: bool):
+    pad = (-curr.shape[0]) % chunk
     if pad:                              # equal zero padding: never "changed"
-        curr8 = jnp.pad(curr8, (0, pad))
-        base8 = jnp.pad(base8, (0, pad))
-    uk = _on_tpu() if use_kernel is None else use_kernel
-    if uk:                               # byte compare: exact for any dtype
-        flags = delta_blocks_kernel(curr8, base8, chunk, interpret=interpret)
+        curr = jnp.pad(curr, (0, pad))
+        base = jnp.pad(base, (0, pad))
+    if use_kernel:                       # word compare: exact for any dtype
+        flags = delta_blocks_kernel(curr, base, chunk, interpret=interpret)
     else:
-        flags = delta_blocks_ref(curr8, base8, chunk)
+        flags = delta_blocks_ref(curr, base, chunk)
     return flags.astype(jnp.int8)        # D2H: 1 B per chunk
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
-def _gather_chunks(curr8, idx, *, chunk: int):
-    pad = (-curr8.shape[0]) % chunk
+def _gather_chunks(flat, idx, *, chunk: int):
+    flat = _raw(flat)
+    pad = (-flat.shape[0]) % chunk
     if pad:
-        curr8 = jnp.pad(curr8, (0, pad))
-    return curr8.reshape(-1, chunk)[idx]
+        flat = jnp.pad(flat, (0, pad))
+    return flat.reshape(-1, chunk)[idx]
 
 
 def delta_encode(curr, base, *, chunk_bytes: int = DELTA_CHUNK_BYTES,
                  use_kernel: bool | None = None, interpret: bool = False):
     """Differential encode of ``curr`` against ``base`` (both device arrays
-    of identical byte size, any dtype), comparing raw bytes per
-    ``chunk_bytes``-sized chunk on device.
+    of one dtype and size; ``chunk_bytes`` a multiple of its itemsize),
+    comparing raw bits per ``chunk_bytes``-sized chunk on device.
 
     Returns ``(idx, payload, d2h_bytes)``: ``idx`` the int32 indices of
     changed chunks, ``payload`` the changed chunks' bytes (final chunk
@@ -370,23 +429,37 @@ def delta_encode(curr, base, *, chunk_bytes: int = DELTA_CHUNK_BYTES,
     what actually crossed device→host (1 B of flag per chunk + the changed
     bytes — an unchanged state costs ~0.05 % of its size).
     """
-    c8 = as_bytes(curr)
-    b8 = as_bytes(base)
-    total = c8.shape[0]
-    if b8.shape[0] != total:
+    curr, base = jnp.ravel(jnp.asarray(curr)), jnp.ravel(jnp.asarray(base))
+    cw, word_bytes = _delta_words(curr)
+    bw, _ = _delta_words(base)
+    total = curr.size * np.dtype(curr.dtype).itemsize
+    if base.size * np.dtype(base.dtype).itemsize != total:
         raise ValueError(
-            f"delta_encode: size mismatch ({total} vs {b8.shape[0]} bytes)")
+            f"delta_encode: size mismatch ({total} vs "
+            f"{base.size * np.dtype(base.dtype).itemsize} bytes)")
     if total == 0:
         return np.zeros(0, np.int32), np.zeros(0, np.uint8), 0
-    flags_h = np.asarray(_delta_flags(c8, b8, chunk=chunk_bytes,
-                                      use_kernel=use_kernel,
-                                      interpret=interpret))
+    chunk = chunk_bytes // word_bytes
+    uk = _on_tpu() if use_kernel is None else use_kernel
+    if uk and (_spans_devices(cw) or _spans_devices(bw)):
+        flags = _per_device(
+            lambda c, b: delta_blocks_kernel(c, b, chunk,
+                                             interpret=interpret),
+            [cw, bw], tile=chunk, pads=[0, 0])
+        flags = flags[:-(-cw.shape[0] // chunk)].astype(jnp.int8)
+    else:
+        flags = _delta_flags(cw, bw, chunk=chunk, use_kernel=uk,
+                             interpret=interpret)
+    flags_h = np.asarray(flags)              # D2H: 1 B per chunk
     d2h = flags_h.nbytes
     idx = np.flatnonzero(flags_h).astype(np.int32)
     if idx.size == 0:
         return idx, np.zeros(0, np.uint8), d2h
-    chunks = np.asarray(_gather_chunks(c8, jnp.asarray(idx),
-                                       chunk=chunk_bytes))
+    # changed chunks leave in the leaf's own width; the host views bytes
+    chunks = np.asarray(_gather_chunks(
+        curr, jnp.asarray(idx),
+        chunk=chunk_bytes // np.dtype(curr.dtype).itemsize))
+    chunks = chunks.reshape(len(idx), -1).view(np.uint8)
     nc = -(-total // chunk_bytes)
     tail = total - (nc - 1) * chunk_bytes
     if int(idx[-1]) == nc - 1 and tail < chunk_bytes:

@@ -42,11 +42,10 @@ def pack_blocks_ref(flat: jnp.ndarray, mask: jnp.ndarray, block: int = BLOCK):
     vb = flat.reshape(-1, block)
     mb = mask.reshape(-1, block)
     pos = jnp.cumsum(mb, axis=1) - 1                       # target slot
-    idx = jnp.where(mb, pos, block - 1)
+    idx = jnp.where(mb, pos, block)                        # block: dropped
     rows = jnp.arange(vb.shape[0])[:, None]
-    # non-critical elements contribute 0 to slot block-1 (add is exact:
-    # every slot receives at most one critical value)
-    packed = jnp.zeros_like(vb).at[rows, idx].add(jnp.where(mb, vb, 0))
+    # a set (not an add onto zeros) keeps every bit, -0.0 included
+    packed = jnp.zeros_like(vb).at[rows, idx].set(vb, mode="drop")
     counts = mb.sum(axis=1).astype(jnp.int32)
     return packed, counts
 

@@ -1,73 +1,43 @@
-"""JAX persistent compilation cache wiring for bench + launch paths.
+"""JAX persistent compilation cache wiring for the launch paths.
 
-The device scrutiny engine's multi-probe vjp sweep costs ~2 s of XLA
-compile the first time a (state structure, probe count) pair is seen —
-per *process*, so every training relaunch and every benchmark run pays
-it again even though the jaxpr is identical.  XLA's persistent
-compilation cache keys serialized executables on the HLO fingerprint and
-serves later compiles from disk, turning the relaunch cost into a
-millisecond-scale cache read.
+The train step and the scrutiny sweep take seconds to minutes to compile,
+once per *process*, so every relaunch and ``--resume`` would pay it again
+though the program is identical.  XLA's persistent compilation cache keys
+serialized executables on the HLO fingerprint and serves later compiles
+from disk.
 
-``enable_persistent_cache()`` points JAX at a stable on-disk cache
-directory (``$REPRO_COMPILE_CACHE``, or ``~/.cache/repro/jax`` when
-unset; ``REPRO_COMPILE_CACHE=0`` disables) and drops the min-compile-time
-/ min-entry-size thresholds so the scrutiny sweep and the packed-save
-kernels are always cached.  Every knob is set best-effort: older JAX
-versions without a given config simply skip it, and a read-only cache
-directory disables the cache rather than failing the launch.
+``enable_persistent_cache()`` keeps the cache where
+``$JAX_COMPILATION_CACHE_DIR`` says when it is set, and otherwise in one
+fixed directory inside the checkout (``<repo>/.jax_cache``, git-ignored):
+the path is part of what a later process looks up, so it never moves.
+It also drops the min-compile-time / min-entry-size thresholds so the
+small helper jits on the relaunch path are cached too.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-_DISABLE = ("0", "off", "none", "disable")
+ENV = "JAX_COMPILATION_CACHE_DIR"
 
-
-def default_cache_dir() -> Optional[str]:
-    """Resolve the cache dir from ``$REPRO_COMPILE_CACHE`` (None = off)."""
-    env = os.environ.get("REPRO_COMPILE_CACHE")
-    if env is not None:
-        return None if env.strip().lower() in _DISABLE else env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro", "jax")
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Turn on JAX's persistent compilation cache.
+def cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` if set, else ``<repo>/.jax_cache``."""
+    return os.environ.get(ENV) or REPO_CACHE_DIR
 
-    Returns the active cache directory, or None when disabled (explicitly
-    via env, or because the directory cannot be created).  Idempotent and
-    safe to call before any jit compilation in a process.
-    """
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+    Idempotent; call before the first jit compilation of the process."""
     import jax
 
-    d = cache_dir if cache_dir is not None else default_cache_dir()
-    if d is None:
-        return None
-    try:
-        os.makedirs(d, exist_ok=True)
-    except OSError:
-        return None
-    opts = [
-        ("jax_compilation_cache_dir", d),
-        # cache everything: the scrutiny sweep's helper jits are small but
-        # sit on the relaunch path too
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        # cover the XLA-side autotune/kernel caches where supported
-        ("jax_persistent_cache_enable_xla_caches", "all"),
-    ]
-    for name, value in opts:
-        try:
-            jax.config.update(name, value)
-        except (AttributeError, ValueError, TypeError):
-            pass                    # older JAX: knob absent — best effort
-    try:
-        # the cache object is initialized lazily *once*; re-pointing the
-        # dir mid-process (bench cold/warm runs) needs an explicit reset
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    except (ImportError, AttributeError):
-        pass
+    d = cache_dir()
+    os.makedirs(d, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return d

@@ -22,7 +22,22 @@ def make_host_mesh(data: int = 1, model: int = 1):
     return jax.make_mesh((data, model), ("data", "model"))
 
 
-# Hardware constants for the roofline model (TPU v5e).
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect = 4 links x 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+# The production mesh above is a v5e pod: the dry-run roofline's target.
+PRODUCTION_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak bf16 FLOP/s, HBM bytes/s and ICI bytes/s per link of one chip
+    of ``device_kind``; a kind not in :data:`PEAKS` is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})") from None

@@ -4,6 +4,9 @@
     memory     = HLO_bytes / (chips × 819 GB/s)
     collective = collective_bytes / (chips × 50 GB/s/link)
 
+with the published peaks of the production mesh's chip (TPU v5e,
+``launch.mesh.PEAKS``).
+
 FLOPs/bytes come from ``compiled.cost_analysis()``; collective bytes are
 parsed from the HLO text (all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute operand sizes).  MODEL_FLOPS = 6·N·D
@@ -18,7 +21,10 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS
+from repro.launch.mesh import PRODUCTION_KIND, peaks
+
+_PEAK = peaks(PRODUCTION_KIND)
+PEAK_FLOPS, HBM_BW, ICI_BW = _PEAK["flops"], _PEAK["hbm_bw"], _PEAK["ici_bw"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

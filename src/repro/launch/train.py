@@ -16,8 +16,15 @@ owns, the step commits via the collective two-phase protocol, and
 On a single process the coordinator delegates to the pipelined async
 manager, so the wiring is unconditional.
 
-``--preset smoke`` shrinks the model (CPU CI); on real hardware use the
-full config with --mesh data,model sizes.
+``--preset smoke`` shrinks the model (CPU CI); ``--preset full`` runs the
+published widths (``chip_smoke.py`` drives it on one TPU chip).
+
+With ``--scrutinize`` / ``--verify-static`` "the rest of the program" is
+the next train step, and its output is everything the run after that
+reads: the loss *and* the next params, optimizer state and data state.
+Only then does a resume from the reduced checkpoint reproduce the
+uninterrupted losses; an output of the loss alone marks the AdamW moments
+uncritical (the loss never reads them) and the resumed run diverges.
 """
 
 from __future__ import annotations
@@ -104,12 +111,14 @@ def main(argv=None):
     soundness_check = None
     if args.scrutinize or args.verify_static:
         # "the rest of the program" for a train checkpoint: the next train
-        # step from the data pipeline's next batch.  One stable fn object,
-        # so the shared jaxpr trace cache hits across scrutiny/static/lint.
+        # step from the data pipeline's next batch, returning the state the
+        # step after it reads.  One stable fn object, so the shared jaxpr
+        # trace cache hits across scrutiny/static/lint.
         def resume(s):
-            batch, _ = data_pipeline.next_batch(cfg, s["data"])
-            _, _, metrics = step_fn(s["params"], s["opt"], batch)
-            return {"loss": metrics["loss"]}
+            batch, data = data_pipeline.next_batch(cfg, s["data"])
+            params, opt, metrics = step_fn(s["params"], s["opt"], batch)
+            return {"loss": metrics["loss"], "params": params, "opt": opt,
+                    "data": data}
 
         if args.verify_static:
             from repro.analysis import soundness_checker

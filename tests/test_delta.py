@@ -406,9 +406,6 @@ def test_multidevice_segment_paths():
     """Per-shard pack + per-segment scatter restore on 4 virtual CPU
     devices (XLA device-count flag must be set before jax init → run in a
     subprocess)."""
-    import subprocess
-    import sys
-
     prog = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -421,8 +418,8 @@ arr = rng.randn(64, 32).astype(np.float32)
 mask = rng.rand(64 * 32) < 0.3
 payload = arr.reshape(-1)[mask]
 leaf = jax.device_put(jnp.asarray(arr), s)
-pd, counts, moved = sh.pack_sharded_payload_device(leaf, mask,
-                                                   interpret=True)
+pd, counts, moved, _ = sh.pack_sharded_payload_device(leaf, mask,
+                                                      interpret=True)
 np.testing.assert_array_equal(np.asarray(pd), payload)
 out, h2d = sh.scatter_sharded_payload(payload, mask, (64, 32), np.float32,
                                       s, fill=0, interpret=True)
@@ -442,8 +439,23 @@ out2, _ = sh.scatter_sharded_payload(pay2, mask2, (64, 32), np.float32,
 np.testing.assert_array_equal(np.asarray(out2),
                               np.where(mask2, arr.reshape(-1), 0)
                               .reshape(64, 32))
+# every segment empty: each piece still lands on its own device
+out3, _ = sh.scatter_sharded_payload(arr.reshape(-1)[:0],
+                                     np.zeros(64 * 32, bool), (64, 32),
+                                     np.float32, s, fill=0, interpret=True)
+assert len(out3.sharding.device_set) == 4
+np.testing.assert_array_equal(np.asarray(out3), np.zeros((64, 32)))
 print("MULTIDEVICE_OK")
 """
+    assert "MULTIDEVICE_OK" in _run_on_four_devices(prog)
+
+
+def _run_on_four_devices(prog: str) -> str:
+    """Run ``prog`` in a fresh interpreter that sees 4 virtual CPU devices
+    (the XLA device-count flag must be set before jax initializes)."""
+    import subprocess
+    import sys
+
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                JAX_PLATFORMS="cpu")
@@ -452,4 +464,120 @@ print("MULTIDEVICE_OK")
         + env.get("PYTHONPATH", "").split(os.pathsep))
     res = subprocess.run([sys.executable, "-c", prog], env=env,
                          capture_output=True, text=True, timeout=300)
-    assert "MULTIDEVICE_OK" in res.stdout, res.stderr
+    assert res.returncode == 0, res.stderr
+    return res.stdout
+
+
+def test_multidevice_kernel_paths():
+    """Leaves that span several devices reach the Pallas kernels one
+    device's piece at a time (a kernel cannot be partitioned): layouts off
+    the leading axis are re-tiled into per-device row ranges and counted
+    in ``last_save_stats``; bitpack and delta run per device.  Kernels in
+    interpret mode; every result byte-identical to the host path."""
+    prog = r"""
+import os, tempfile
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.checkpoint import CheckpointManager, Level
+from repro.checkpoint.coordinator import CoordinatedCheckpointManager
+from repro.core.criticality import CriticalityReport, LeafReport
+from repro.core.policy import LeafPolicy
+from repro.core.regions import RegionTable
+from repro.distributed import sharding as sh
+from repro.kernels.mask_pack import ops as mp
+mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
+put = lambda x, spec: jax.device_put(jnp.asarray(x), NamedSharding(mesh, spec))
+rng = np.random.RandomState(0)
+arr = rng.randn(64, 48).astype(np.float32)
+arr.reshape(-1)[:5] = [-0.0, np.nan, np.inf, -np.inf, -0.0]
+mask = rng.rand(arr.size) < 0.3
+kern = dict(use_kernel=True, interpret=True)
+
+# per-device pack: leading-axis shards as they are, other layouts re-tiled
+for spec, retiled in [(P(None, "model"), True), (P("data", "model"), True),
+                      (P("data"), False), (P(), False)]:
+    pd, _, _, r = sh.pack_sharded_payload_device(put(arr, spec), mask, **kern)
+    assert r == retiled, spec
+    assert np.asarray(pd).tobytes() == arr.reshape(-1)[mask].tobytes(), spec
+try:
+    mp.pack_group([jnp.ravel(put(arr, P(None, "model")))], [jnp.asarray(mask)],
+                  [int(mask.sum())], **kern)
+    raise SystemExit("pack_group took a leaf on several devices")
+except ValueError:
+    pass
+
+# bitpack and delta on operands that span devices == the one-device oracle
+for x in (rng.randn(20000), rng.randn(3 * 4 * 1024), rng.randn(3001)):
+    for spec in (P(("data", "model")), P()):
+        if spec != P() and x.size % 4:
+            continue
+        mag = put(np.abs(x).astype(np.float32), spec)
+        w, c = mp.threshold_bitpack(mag, 0.5, **kern)
+        rw, rc = mp.threshold_bitpack(np.asarray(mag), 0.5, use_kernel=False)
+        assert np.asarray(w).tobytes() == np.asarray(rw).tobytes()
+        assert np.asarray(c).tobytes() == np.asarray(rc).tobytes()
+a = rng.randn(7000).astype(np.float32); b = a.copy(); b[[3, 2500, 6999]] += 1
+for spec in (P(("data", "model")), P()):
+    got = mp.delta_encode(put(b, spec), put(a, spec), **kern)
+    want = mp.delta_encode(jnp.asarray(b), jnp.asarray(a), use_kernel=False)
+    assert (got[0] == want[0]).all() and got[1].tobytes() == want[1].tobytes()
+    assert got[2] == want[2]
+
+# manager: device base + delta save == host save, file for file
+state = {"w": put(arr, P(None, "model")), "v": put(2 * arr, P("data", "model")),
+         "u": put(arr[:, :8] + 1, P("data")),
+         "k": put(np.arange(12, dtype=np.int32), P()), "s": put(np.float32(3), P())}
+masks = {"w": mask, "v": ~mask, "u": mask[:512]}
+leaves = {}
+for name, leaf in state.items():
+    m = masks.get(name, np.ones(max(leaf.size, 1), bool))
+    leaves[name] = LeafReport(
+        name=name, shape=tuple(leaf.shape), dtype=np.dtype(leaf.dtype),
+        policy=LeafPolicy.AD, mask=m, magnitude=None,
+        table=RegionTable.from_mask(m, np.dtype(leaf.dtype).itemsize))
+report = CriticalityReport(leaves=leaves)
+bumped = arr + (np.arange(arr.size).reshape(arr.shape) == 700)
+changed = dict(state, w=put(bumped, P(None, "model")), s=put(np.float32(4), P()))
+tmp = tempfile.mkdtemp()
+files = {}
+for mode in ("host", "device"):
+    d = os.path.join(tmp, mode)
+    with CheckpointManager([Level(d, max_chain=2)], scrutiny_fn=lambda s: report,
+                           save_mode=mode, pipeline_engine="xla",
+                           pack_interpret=True, pack_use_kernel=True) as mgr:
+        mgr.save(1, state, block=True)
+        st = mgr.wait()
+        if mode == "device":
+            assert st["engine"] == "xla" and st["packed_leaves"] == 3
+            assert st["retiled_leaves"] == 2
+            assert st["retiled_bytes"] == 2 * arr.nbytes
+        mgr.save(2, changed, block=True)
+        assert list(mgr.wait()["levels"].values())[0]["kind"] == "delta"
+        _, back = mgr.restore(state, mode=mode)
+    want = np.where(mask, bumped.reshape(-1), 0).reshape(arr.shape)
+    assert np.asarray(back["w"]).tobytes() == want.tobytes()
+    files[mode] = {}
+    for dp, _, fs in os.walk(d):
+        for f in fs:
+            with open(os.path.join(dp, f), "rb") as fh:
+                files[mode][os.path.relpath(os.path.join(dp, f), d)] = fh.read()
+assert files["device"] and files["device"] == files["host"]
+
+# coordinated save: a segment of a leaf on several devices packs per device
+d = os.path.join(tmp, "coord")
+mgr = CoordinatedCheckpointManager(
+    [Level(d)], scrutiny_fn=lambda s: report, save_mode="device",
+    force_coordinated=True, pipeline_engine="xla", pack_use_kernel=True,
+    pack_interpret=True)
+mgr.save(1, state)
+mgr.wait()
+_, got = mgr.restore(state)
+mgr.close()
+for k, x in state.items():
+    want = np.asarray(x).reshape(-1).copy()
+    if k in masks:
+        want[~masks[k]] = 0
+    assert np.asarray(got[k]).reshape(-1).tobytes() == want.tobytes(), k
+print("KERNEL_PATHS_OK")
+"""
+    assert "KERNEL_PATHS_OK" in _run_on_four_devices(prog)

@@ -19,7 +19,10 @@ from repro.checkpoint.packing import unpack_leaf
 from repro.core.criticality import CriticalityReport, LeafReport
 from repro.core.policy import LeafPolicy
 from repro.core.regions import RegionTable
+from repro.checkpoint.packing import delta_encode_host
 from repro.kernels.mask_pack import ops as mp_ops
+from repro.kernels.mask_pack.kernel import BITPACK_BLOCK, BLOCK, MAX_ROWS
+from repro.kernels.mask_pack.ops import DELTA_CHUNK_BYTES
 
 DTYPES = [jnp.float32, jnp.bfloat16, jnp.int32]
 DENSITIES = [0.0, 0.03, 0.5, 1.0]
@@ -94,7 +97,7 @@ def test_pack_padding_any_size(n):
 
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_device_restore_roundtrip(dtype):
-    """scatter_payload + unpack re-expands the payload on device."""
+    """unpack_critical re-expands the payload on device."""
     n = 2000
     vals = _vals(n, dtype, seed=3)
     mask = _mask(n, 0.3, seed=4)
@@ -169,6 +172,121 @@ def test_manager_device_vs_host_disk_identical(tmp_path, dtype, frac):
         w[~masks["w"]] = 0
     np.testing.assert_array_equal(leaves["w"].reshape(-1), w)
     np.testing.assert_array_equal(leaves["s"], 5)
+
+
+# --------------------------------------------------------------------------
+# past MAX_ROWS tiles: the kernels stop padding their grid, the last grid
+# step reads a partial block and the wrapper slices off what it computed
+# past the end.  Payloads hold -0.0, infinities and NaNs with distinct
+# payload bits; every path must keep them bit for bit.
+# --------------------------------------------------------------------------
+
+BIG = (MAX_ROWS + 1) * BLOCK + 300              # 130 pack tiles
+BIG_BITPACK = (MAX_ROWS + 1) * BITPACK_BLOCK + 300
+SPECIAL_BITS = {jnp.float32: [0x80000000, 0x7FC00123, 0xFFC00456,
+                              0x7F800000, 0xFF800000],
+                jnp.bfloat16: [0x8000, 0x7FC1, 0xFFC3, 0x7F80, 0xFF80]}
+
+
+def _special_vals(n, dtype, seed=0):
+    rng = np.random.RandomState(seed)
+    x = rng.randn(n).astype(np.dtype(dtype))
+    u = x.view(np.uint32 if dtype == jnp.float32 else np.uint16)
+    at = rng.choice(n, n // 50, replace=False)
+    u[at] = np.resize(SPECIAL_BITS[dtype], at.size)
+    u[-1] = SPECIAL_BITS[dtype][1]              # a NaN in the partial tile
+    return x
+
+
+def _bits(x) -> bytes:
+    return np.asarray(x).tobytes()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pack_unpack_scatter_past_max_rows(dtype):
+    vals = _special_vals(BIG, dtype)
+    mask = _mask(BIG, 0.4, seed=2)
+    mask[-1] = True
+    want_payload = vals[mask]
+    want = np.where(mask, vals, np.zeros((), vals.dtype))
+    pk_k, cnt_k = mp_ops.pack(jnp.asarray(vals), jnp.asarray(mask),
+                              use_kernel=True, interpret=True)
+    pk_r, cnt_r = mp_ops.pack(jnp.asarray(vals), jnp.asarray(mask),
+                              use_kernel=False)
+    assert _bits(cnt_k) == _bits(cnt_r)
+    for pk, cnt in ((pk_k, cnt_k), (pk_r, cnt_r)):
+        payload = mp_ops.pack_to_payload(np.asarray(pk), np.asarray(cnt))
+        assert _bits(payload) == _bits(want_payload)
+    back = mp_ops.unpack(pk_k, jnp.asarray(mask), n=BIG, use_kernel=True,
+                         interpret=True)
+    assert _bits(back) == _bits(want)
+    for use_kernel in (True, False):
+        out = mp_ops.mask_scatter(jnp.asarray(want_payload),
+                                  jnp.asarray(mask), n=BIG,
+                                  use_kernel=use_kernel, interpret=True)
+        assert _bits(out) == _bits(want)
+
+
+def test_bitpack_past_max_rows():
+    rng = np.random.RandomState(5)
+    mag = np.abs(rng.randn(BIG_BITPACK)).astype(np.float32)
+    mag[rng.choice(mag.size, 500, replace=False)] = 0.0
+    mag[-3:] = [np.inf, 0.5, 0.7]
+    w_k, c_k = mp_ops.threshold_bitpack(jnp.asarray(mag), 0.5,
+                                        use_kernel=True, interpret=True)
+    w_r, c_r = mp_ops.threshold_bitpack(jnp.asarray(mag), 0.5,
+                                        use_kernel=False)
+    assert _bits(w_k) == _bits(w_r) == _bits(np.packbits(mag > 0.5))
+    assert _bits(c_k) == _bits(c_r)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_delta_past_max_rows(dtype):
+    itemsize = np.dtype(dtype).itemsize
+    n = (MAX_ROWS + 1) * DELTA_CHUNK_BYTES // itemsize + 300
+    base = _special_vals(n, dtype, seed=6)
+    curr = base.copy()
+    u = curr.view(np.uint32 if dtype == jnp.float32 else np.uint16)
+    nan, neg_zero = SPECIAL_BITS[dtype][1], SPECIAL_BITS[dtype][0]
+    curr[5] = 0.0 if u[5] != 0 else 1.0
+    u[n // 2] = neg_zero if u[n // 2] != neg_zero else 0  # -0.0 vs +0.0
+    u[-1] = nan + 1 if u[-1] == nan else nan              # NaN payload bits
+    got = mp_ops.delta_encode(jnp.asarray(curr), jnp.asarray(base),
+                              use_kernel=True, interpret=True)
+    ref = mp_ops.delta_encode(jnp.asarray(curr), jnp.asarray(base),
+                              use_kernel=False)
+    idx, pay = delta_encode_host(curr, base, DELTA_CHUNK_BYTES)
+    assert _bits(got[0]) == _bits(ref[0]) == _bits(idx)
+    assert _bits(got[1]) == _bits(ref[1]) == _bits(pay)
+    assert len(idx) == 3
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_manager_device_vs_host_past_max_rows(tmp_path, dtype):
+    """The xla save engine (pack kernel) writes the host path's bytes, and
+    a device restore (scatter kernel) gives the host restore's bits."""
+    state = {"w": jnp.asarray(_special_vals(BIG, dtype, seed=9))}
+    mask = _mask(BIG, 0.3, seed=10)
+    report = _report(state, {"w": mask})
+    files, back = {}, {}
+    for mode in ("host", "device"):
+        d = str(tmp_path / mode)
+        with CheckpointManager([Level(d)], scrutiny_fn=lambda s: report,
+                               save_mode=mode, pipeline_engine="xla",
+                               pack_interpret=True,
+                               pack_use_kernel=True) as mgr:
+            mgr.save(1, state, block=True)
+            assert mgr.wait()["mode"] == mode
+            _, back[mode] = mgr.restore(state, mode=mode)
+        files[mode] = {}
+        for f in sorted(os.listdir(os.path.join(d, "step_1"))):
+            with open(os.path.join(d, "step_1", f), "rb") as fh:
+                files[mode][f] = fh.read()
+    assert files["device"] and files["device"] == files["host"]
+    want = np.where(mask, np.asarray(state["w"]),
+                    np.zeros((), np.dtype(dtype)))
+    assert _bits(back["device"]["w"]) == _bits(back["host"]["w"]) \
+        == _bits(want)
 
 
 # --------------------------------------------------------------------------

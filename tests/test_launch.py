@@ -102,3 +102,27 @@ def test_roofline_terms():
     assert abs(rl.t_collective - 0.005) < 1e-9
     assert rl.dominant == "memory"
     assert abs(rl.roofline_fraction - 0.4) < 1e-9
+
+
+def test_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import PEAKS, PRODUCTION_KIND, peaks
+
+    v5e = peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    assert PRODUCTION_KIND in PEAKS
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+
+
+def test_compile_cache_dir(monkeypatch):
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV, "/some/cache")
+    assert compile_cache.cache_dir() == "/some/cache"
+    monkeypatch.delenv(compile_cache.ENV)
+    d = compile_cache.cache_dir()
+    assert d == compile_cache.REPO_CACHE_DIR
+    assert d.endswith(".jax_cache")
+    # inside the checkout, next to src/
+    import os
+    assert os.path.isdir(os.path.join(os.path.dirname(d), "src", "repro"))

@@ -45,6 +45,29 @@ def test_restart_equivalence(tmp_path):
                                err_msg="restart diverged from straight run")
 
 
+@pytest.mark.parametrize("flag", ["--scrutinize", "--verify-static"])
+def test_restart_equivalence_scrutinized(tmp_path, flag):
+    """12 steps with scrutinized saves vs 6 + crash + resume + 6: the
+    losses are identical bit for bit.  The scrutinized "rest of the
+    program" must return the whole next train state: with the loss alone,
+    the AdamW moments read nowhere else would be dropped from the
+    checkpoint."""
+    import shutil
+
+    args = ["--steps", "12", "--batch", "2", "--seq", "32",
+            "--ckpt-every", "6", "--ckpt-dir", str(tmp_path), flag]
+    full = train_main(args)
+    for level in ("ram", "disk"):
+        d = tmp_path / level
+        if d.exists():
+            for sub in d.iterdir():
+                if sub.name.startswith("step_") and \
+                        int(sub.name.split("_")[1]) > 6:
+                    shutil.rmtree(sub)
+    resumed = train_main(args + ["--resume"])
+    assert resumed == full[6:], "restart diverged from straight run"
+
+
 def test_data_pipeline_deterministic_resume():
     cfg = get_config("xlstm-125m").reduced()
     s0 = dp.init_state(cfg, 2, 16, seed=3)
