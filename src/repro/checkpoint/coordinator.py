@@ -677,8 +677,11 @@ class CoordinatedCheckpointManager:
                                      state, check=self.soundness_check)
         if ran:
             # live view, deliberately not frozen: device reports account
-            # their lazy mask D2H into these stats after publication
+            # their lazy mask D2H into these stats after publication; the
+            # registry keeps a frozen copy as they stand now
             self.last_scrutiny_stats = getattr(new, "stats", None)
+            if self.last_scrutiny_stats is not None:
+                self.obs.registry.publish("scrutiny", self.last_scrutiny_stats)
             if new is not None and self.obs.enabled:
                 with self.obs.tracer.span("scrutiny.drift"):
                     self.obs.drift.observe(new, step=self._saves)
@@ -958,25 +961,24 @@ class CoordinatedCheckpointManager:
             if kind == "delta":
                 prev_sources = plan["prev_sources"]
                 entries = []
-                delta_span = h.stage("delta", level=lv.directory)
-                delta_span.__enter__()
-                for name, flo, fhi, meta, payload in items:
-                    curr = sources[(name, flo, fhi)]
-                    prev = prev_sources[(name, flo, fhi)]
-                    idx, pay = delta_encode_host(curr, prev,
-                                                 self.delta_chunk_bytes)
-                    pay_b = pay.tobytes()
-                    d = DeltaLeaf(name=name, shape=tuple(meta["shape"]),
-                                  dtype=meta["dtype"],
-                                  chunk_bytes=self.delta_chunk_bytes,
-                                  total_bytes=int(curr.nbytes), idx=idx,
-                                  payload=pay_b, checksum=zlib.crc32(pay_b))
-                    dm = _delta_entry(d)
-                    dm.update(shape=meta["shape"], start=meta["start"],
-                              stop=meta["stop"])
-                    entries.append((dm, len(d.payload),
-                                    BytesSource(bytes(d.payload))))
-                delta_span.__exit__(None, None, None)
+                with h.stage("delta", level=lv.directory):
+                    for name, flo, fhi, meta, payload in items:
+                        curr = sources[(name, flo, fhi)]
+                        prev = prev_sources[(name, flo, fhi)]
+                        idx, pay = delta_encode_host(curr, prev,
+                                                     self.delta_chunk_bytes)
+                        pay_b = pay.tobytes()
+                        d = DeltaLeaf(name=name, shape=tuple(meta["shape"]),
+                                      dtype=meta["dtype"],
+                                      chunk_bytes=self.delta_chunk_bytes,
+                                      total_bytes=int(curr.nbytes), idx=idx,
+                                      payload=pay_b,
+                                      checksum=zlib.crc32(pay_b))
+                        dm = _delta_entry(d)
+                        dm.update(shape=meta["shape"], start=meta["start"],
+                                  stop=meta["stop"])
+                        entries.append((dm, len(d.payload),
+                                        BytesSource(bytes(d.payload))))
             else:
                 # zero-copy chunked streams over the packed host payloads
                 # (stage-2 reuse: the writer consumes ViewSource chunks)

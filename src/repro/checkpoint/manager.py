@@ -700,8 +700,11 @@ class CheckpointManager:
                                      state, check=self.soundness_check)
         if ran:
             # live view, not frozen: device reports account their lazy
-            # mask D2H into this dict when materialized
+            # mask D2H into this dict when materialized; the registry
+            # keeps a frozen copy as it stands now
             self.last_scrutiny_stats = getattr(new, "stats", None)
+            if self.last_scrutiny_stats is not None:
+                self.obs.registry.publish("scrutiny", self.last_scrutiny_stats)
             if new is not None and self.obs.enabled:
                 with self.obs.tracer.span("scrutiny.drift"):
                     self.obs.drift.observe(new, step=self._saves)

@@ -46,7 +46,9 @@ other and against the paper in tests/ and EXPERIMENTS.md.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -55,6 +57,7 @@ import numpy as np
 from jax import lax
 from jax.extend import core as jex_core
 
+from repro import obs as obs_mod
 from repro.core.bitset import BitMask
 from repro.core.criticality import (CriticalityReport, LeafReport, _path_str,
                                     traced_step)
@@ -882,29 +885,53 @@ def participation(
     marks an element critical iff the remaining computation transitively
     reads it before overwriting it.  See module docstring for how this
     relates to the AD (vjp) engine.
+
+    The walk's four phases are spans (``scrutiny.trace``, ``.forward``,
+    ``.taint``, ``.tables``), and their seconds are always in the report's
+    ``stats["stages"]`` (``trace_s``, ``forward_s``, ``taint_s``,
+    ``tables_s``).
     """
-    ts = traced_step(fn, state)
-    names, leaves = ts.names, ts.leaves
-    policies = [config.leaf_policy(l) for l in leaves]
-    in_taints = backward_taint(ts.closed, leaves)
+    tracer = obs_mod.get_obs().tracer
+    stages: Dict[str, float] = {}
+
+    @contextlib.contextmanager
+    def stage(name: str):
+        t0 = time.perf_counter()
+        with tracer.span("scrutiny." + name):
+            yield
+        stages[name + "_s"] = time.perf_counter() - t0
+
+    with stage("trace"):
+        ts = traced_step(fn, state)
+    names, leaves, closed = ts.names, ts.leaves, ts.closed
+    env: Dict[Any, Any] = {}
+    with stage("forward"):
+        _forward_env(closed.jaxpr, closed.consts, list(leaves), env)
+    with stage("taint"):
+        out_taints = [np.ones(_shape(v), bool) for v in closed.jaxpr.outvars]
+        in_taints = _backward(closed.jaxpr, closed.consts, out_taints, env)
+    del env
 
     reports: Dict[str, LeafReport] = {}
-    for i, (name, leaf, pol) in enumerate(zip(names, leaves, policies)):
-        n = int(np.prod(leaf.shape)) if leaf.ndim else 1
-        if pol in (LeafPolicy.AD, LeafPolicy.HORIZON):
-            mask = in_taints[i].reshape(-1).copy()
-            if mask.size == 0 and n == 1:
-                mask = np.zeros(1, bool)
-        elif pol == LeafPolicy.ALWAYS_CRITICAL:
-            mask = np.ones(n, dtype=bool)
-        else:
-            mask = np.zeros(n, dtype=bool)
-        if mask.size != n:  # 0-d leaves
-            mask = np.resize(mask, n)
-        table = RegionTable.from_mask(mask, itemsize=np.dtype(leaf.dtype).itemsize)
-        table.validate()
-        reports[name] = LeafReport(
-            name=name, shape=tuple(leaf.shape), dtype=np.dtype(leaf.dtype),
-            policy=pol, mask=mask, table=table, magnitude=None,
-        )
-    return CriticalityReport(leaves=reports)
+    with stage("tables"):
+        policies = [config.leaf_policy(l) for l in leaves]
+        for i, (name, leaf, pol) in enumerate(zip(names, leaves, policies)):
+            n = int(np.prod(leaf.shape)) if leaf.ndim else 1
+            if pol in (LeafPolicy.AD, LeafPolicy.HORIZON):
+                mask = in_taints[i].reshape(-1).copy()
+                if mask.size == 0 and n == 1:
+                    mask = np.zeros(1, bool)
+            elif pol == LeafPolicy.ALWAYS_CRITICAL:
+                mask = np.ones(n, dtype=bool)
+            else:
+                mask = np.zeros(n, dtype=bool)
+            if mask.size != n:  # 0-d leaves
+                mask = np.resize(mask, n)
+            table = RegionTable.from_mask(
+                mask, itemsize=np.dtype(leaf.dtype).itemsize)
+            table.validate()
+            reports[name] = LeafReport(
+                name=name, shape=tuple(leaf.shape), dtype=np.dtype(leaf.dtype),
+                policy=pol, mask=mask, table=table, magnitude=None,
+            )
+    return CriticalityReport(leaves=reports, stats={"stages": stages})

@@ -10,11 +10,14 @@ tests/test_data_pipeline.py)."""
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs as obs_mod
 
 PREFETCH = 4  # batches held in the ring buffer
 
@@ -61,7 +64,21 @@ def _fill(cfg, key, start_step, batch, seq, n):
 
 
 def next_batch(cfg, state) -> Tuple[Dict[str, jnp.ndarray], Dict[str, Any]]:
-    """Pop one batch; refill the consumed slot deterministically."""
+    """Pop one batch; refill the consumed slot deterministically.
+
+    The span ``data.next_batch`` is the train step's host work.  Once the
+    device's queue is full, one of its eager dispatches waits there for a
+    free slot, so the span's length holds that wait too; the span's
+    ``cpu_ms`` is the calling thread's CPU time inside it, which leaves
+    the wait out."""
+    cpu0 = time.thread_time()
+    with obs_mod.get_obs().tracer.span("data.next_batch") as span:
+        out = _next_batch(cfg, state)
+        span.set(cpu_ms=(time.thread_time() - cpu0) * 1e3)
+    return out
+
+
+def _next_batch(cfg, state):
     cur = state["cursor"]
     tokens = jax.lax.dynamic_index_in_dim(state["buffer"], cur % PREFETCH,
                                           axis=0, keepdims=False)

@@ -93,6 +93,7 @@ def flash_attention_kernel(q, k, v, *, scale: float, causal: bool,
                              nk=nk, bq=bq, bk=bk)
     return pl.pallas_call(
         kern,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),

@@ -55,6 +55,7 @@ def lru_scan_kernel(a: jnp.ndarray, b: jnp.ndarray, h0: jnp.ndarray,
     kern = functools.partial(_kernel, bt=bt, nt=nt)
     return pl.pallas_call(
         kern,
+        name="lru_scan",
         grid=(B * nr, nt),
         in_specs=[
             pl.BlockSpec((1, bt, br), lambda g, t, nr=nr: (g // nr, t, g % nr)),

@@ -177,6 +177,7 @@ def pack_blocks_kernel(flat: jnp.ndarray, mask_i8: jnp.ndarray,
     mb = _pad_rows(mask_i8.reshape(nb, block).astype(jnp.int32), nb_pad)
     packed, counts = pl.pallas_call(
         functools.partial(_pack_kernel, rows=rows),
+        name="mask_pack_pack",
         grid=(pl.cdiv(nb_pad, rows),),
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
                   pl.BlockSpec((rows, block), lambda i: (i, 0))],
@@ -214,6 +215,7 @@ def unpack_blocks_kernel(packed: jnp.ndarray, mask_i8: jnp.ndarray,
     mb = _pad_rows(mask_i8.reshape(nb, block).astype(jnp.int32), nb_pad)
     out = pl.pallas_call(
         functools.partial(_unpack_kernel, rows=rows),
+        name="mask_pack_unpack",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(pl.cdiv(nb_pad, rows),),
@@ -285,6 +287,7 @@ def scatter_blocks_kernel(payload_pad: jnp.ndarray, starts: jnp.ndarray,
 
     out = pl.pallas_call(
         functools.partial(_scatter_kernel, block=block),
+        name="mask_pack_scatter",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(nb_pad // 8,),
@@ -330,6 +333,7 @@ def bitpack_blocks_kernel(mag: jnp.ndarray, tol,
     mb = _pad_rows(mag.reshape(nb, block), nb_pad, value=-jnp.inf)
     words, counts = pl.pallas_call(
         functools.partial(_bitpack_kernel, block=block),
+        name="mask_pack_bitpack",
         grid=(pl.cdiv(nb_pad, rows),),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec((rows, block), lambda i: (i, 0))],
@@ -358,6 +362,7 @@ def delta_blocks_kernel(curr: jnp.ndarray, base: jnp.ndarray,
     rows, nc_pad = _grid_rows(nc)
     flags = pl.pallas_call(
         _delta_kernel,
+        name="mask_pack_delta",
         grid=(pl.cdiv(nc_pad, rows),),
         in_specs=[pl.BlockSpec((rows, chunk), lambda i: (i, 0)),
                   pl.BlockSpec((rows, chunk), lambda i: (i, 0))],

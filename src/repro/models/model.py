@@ -27,6 +27,7 @@ from repro.models import moe as moe_mod
 from repro.models import recurrent as rec
 from repro.models.layers import (apply_norm, apply_ffn, dtype_of, embed_init,
                                  init_ffn, init_norm, softcap)
+from repro.obs import scopes
 
 # --------------------------------------------------------------------------
 # layer planning
@@ -89,6 +90,16 @@ def _init_mixer(cfg, flavour: str, key):
     raise ValueError(flavour)
 
 
+def _mixer_scope(flavour: str):
+    """The device-trace scope of a block's mixer (``repro.obs.scopes``)."""
+    return jax.named_scope(scopes.ATTENTION if flavour in ("g", "l")
+                           else scopes.RECURRENT)
+
+
+def _ffn_scope(p):
+    return jax.named_scope(scopes.MOE if "moe" in p else scopes.FFN)
+
+
 def init_block(cfg, kind: Kind, key, cross: bool = False):
     fl, ff = kind
     ks = jax.random.split(key, 6)
@@ -132,7 +143,8 @@ def _mixer_train(cfg, kind, p, x, positions):
 def apply_block_train(cfg, kind, p, x, positions, enc_out=None,
                       enc_positions=None, train=False):
     h = apply_norm(cfg, p["norm1"], x)
-    h = _mixer_train(cfg, kind, p, h, positions)
+    with _mixer_scope(kind[0]):
+        h = _mixer_train(cfg, kind, p, h, positions)
     if cfg.post_norm:
         h = apply_norm(cfg, p["norm1_post"], h)
     x = x + h
@@ -143,16 +155,18 @@ def apply_block_train(cfg, kind, p, x, positions, enc_out=None,
     aux = jnp.zeros((), jnp.float32)
     if "ffn" in p or "moe" in p:
         h = apply_norm(cfg, p["norm2"], x)
-        if "moe" in p:
-            h, aux = moe_mod.apply_moe(cfg, p["moe"], h, train=train)
-        else:
-            h = apply_ffn(cfg, p["ffn"], h)
+        with _ffn_scope(p):
+            if "moe" in p:
+                h, aux = moe_mod.apply_moe(cfg, p["moe"], h, train=train)
+            else:
+                h = apply_ffn(cfg, p["ffn"], h)
         if cfg.post_norm:
             h = apply_norm(cfg, p["norm2_post"], h)
         x = x + h
     return x, aux
 
 
+@jax.named_scope(scopes.ATTENTION)
 def _cross_attend(cfg, p, x, enc_out, positions, enc_positions):
     """Encoder-decoder cross attention (whisper); no causal mask."""
     dt = x.dtype
@@ -292,6 +306,7 @@ def _embed_tokens(cfg, params, tokens):
     return x
 
 
+@jax.named_scope(scopes.EMBED)
 def _input_sequence(cfg, params, batch):
     """tokens (+ modality stubs) → (x, positions, text_offset)."""
     x = _embed_tokens(cfg, params, batch["tokens"])
@@ -363,11 +378,14 @@ def _run_encoder(cfg, params, frames):
     # passing causal=False through a local closure.
     def enc_block(x, p_l):
         h = apply_norm(cfg, p_l["norm1"], x)
-        h = attn.attention_train(cfg, p_l["mixer"], h, positions, causal=False)
+        with jax.named_scope(scopes.ATTENTION):
+            h = attn.attention_train(cfg, p_l["mixer"], h, positions,
+                                     causal=False)
         x = x + h
         h = apply_norm(cfg, p_l["norm2"], x)
-        x = x + apply_ffn(cfg, p_l["ffn"], h)
-        return x
+        with jax.named_scope(scopes.FFN):
+            h = apply_ffn(cfg, p_l["ffn"], h)
+        return x + h
 
     def scan_body(carry, p_l):
         return enc_block(carry, p_l["u0"]), None
@@ -446,13 +464,14 @@ def loss_fn(cfg, params, batch):
     # row-length dependent, so eval/prefill/decode paths run dropless).
     x, aux = _run_segments(cfg, params, x, positions, enc_out, enc_pos,
                            train=True)
-    x = apply_norm(cfg, params["final_norm"], x)
-    if offset:
-        x = x[:, offset:]
-    labels = batch["labels"]
-    mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
-    return _chunked_loss(cfg, params, x, labels,
-                         mask.astype(jnp.float32)) + aux
+    with jax.named_scope(scopes.LOSS):
+        x = apply_norm(cfg, params["final_norm"], x)
+        if offset:
+            x = x[:, offset:]
+        labels = batch["labels"]
+        mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
+        loss = _chunked_loss(cfg, params, x, labels, mask.astype(jnp.float32))
+    return loss + aux
 
 
 # --------------------------------------------------------------------------

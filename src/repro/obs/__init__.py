@@ -10,11 +10,17 @@ its own registry + drift tracker and its own process-track in the shared
 trace buffer — all hosts of a thread-simulated run land in *one*
 Perfetto-loadable file.
 
-Observability is **off by default** and off-cheap: ``span()``/``begin()``
-return no-op singletons and metric accessors return a null metric, so the
-instrumented hot paths cost one branch (<2 % on the pack bench, gated in
-CI).  Enable with :func:`enable`, the ``REPRO_OBS=1`` environment
-variable, or per-test via ``enable()``/``disable()`` in a try/finally.
+Two switches.  The profiler is the switch for annotations: every
+same-thread span (``span()``, a handle's ``stage()``) also enters a
+``jax.profiler.TraceAnnotation`` under its own name, which records only
+inside a profiler session, on the device trace's clock.  ``REPRO_OBS``
+is the switch for the Chrome buffer, the metrics and ``telemetry.json``;
+it is **off by default**, and then ``begin()`` returns a null handle,
+``span()`` an annotation alone, and metric accessors a null metric, so
+nothing is buffered, registered or written.  Enable it with
+:func:`enable`, the ``REPRO_OBS=1`` environment variable, or per-test
+via ``enable()``/``disable()`` in a try/finally.  ``repro.obs.scopes``
+names the train step's parts on the device trace.
 
 The managers' ``last_*_stats`` attributes are published *through* the
 registry (:meth:`MetricsRegistry.publish`) as immutable deep-frozen

@@ -1,4 +1,4 @@
-"""Thread-safe span tracer exporting Chrome trace-event JSON.
+"""Thread-safe span tracer: profiler annotations and Chrome trace-event JSON.
 
 Two span shapes cover every path in the checkpoint stack:
 
@@ -18,10 +18,18 @@ Every simulated or real host binds its own ``pid`` (one process-track per
 host in Perfetto) while sharing one :class:`TraceBuffer`, so a thread-
 simulated multi-host run still exports a single loadable trace file.
 
-The disabled fast path allocates nothing: ``span()``/``begin()`` return
-module-level null singletons whose methods are empty — the only cost of
-leaving instrumentation in a hot loop is one attribute load and one
-predictable branch.
+Two switches, for two readers:
+
+* The profiler.  Every same-thread span (``span()`` and ``stage()``) also
+  enters a ``jax.profiler.TraceAnnotation`` under its own name, whatever
+  the switch below says, and what the span's ``set()`` is given becomes
+  the annotation's stats.  An annotation records only while a profiler
+  session is active (``jax.profiler.trace``), and then lands on the host
+  plane of the device trace, on the device's clock.  With no session it
+  costs one check.  The cross-thread ``begin()`` handle has no annotation
+  (an annotation cannot cross threads); its stages do.
+* ``REPRO_OBS`` (:class:`ObsState`).  Off, no Chrome event is buffered:
+  ``begin()`` returns a null handle and ``span()`` an annotation alone.
 """
 
 from __future__ import annotations
@@ -31,6 +39,8 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class ObsState:
@@ -43,7 +53,7 @@ class ObsState:
 
 
 class _NullSpan:
-    """No-op stand-in for both ``span()`` and ``stage()`` results."""
+    """No-op span, for callers that have no tracer at all."""
 
     __slots__ = ()
 
@@ -57,14 +67,32 @@ class _NullSpan:
         pass
 
 
+class _AnnotatedSpan:
+    """A span the profiler alone sees (``REPRO_OBS`` off).  ``set()``
+    gives its annotation stats, as the trace reads them."""
+
+    __slots__ = ("_note",)
+
+    def __init__(self, name: str):
+        self._note = TraceAnnotation(name)
+
+    def __enter__(self) -> "_AnnotatedSpan":
+        self._note.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._note.__exit__(*exc)
+        return False
+
+    def set(self, **args) -> None:
+        self._note.set_metadata(**args)
+
+
 class _NullHandle:
     __slots__ = ()
 
-    def stage(self, name: str, **args) -> _NullSpan:
-        return _NULL_SPAN
-
-    def event(self, name: str, **args) -> None:
-        pass
+    def stage(self, name: str, **args) -> _AnnotatedSpan:
+        return _AnnotatedSpan(name)
 
     def finish(self, **args) -> None:
         pass
@@ -163,13 +191,15 @@ class TraceBuffer:
         return len(doc["traceEvents"])
 
 
-class _Span:
-    """Same-thread complete event (``ph: "X"``)."""
+class _Span(_AnnotatedSpan):
+    """Same-thread complete event (``ph: "X"``), annotated for the
+    profiler too."""
 
     __slots__ = ("_buf", "_pid", "name", "cat", "args", "_t0")
 
     def __init__(self, buf: TraceBuffer, pid: int, name: str, cat: str,
                  args: Dict[str, Any]):
+        super().__init__(name)
         self._buf = buf
         self._pid = pid
         self.name = name
@@ -178,11 +208,13 @@ class _Span:
         self._t0 = 0.0
 
     def __enter__(self) -> "_Span":
+        super().__enter__()
         self._t0 = self._buf.now_us()
         return self
 
     def set(self, **args) -> None:
         self.args.update(args)
+        super().set(**args)
 
     def __exit__(self, *exc) -> bool:
         t1 = self._buf.now_us()
@@ -190,7 +222,7 @@ class _Span:
             "ph": "X", "name": self.name, "cat": self.cat,
             "pid": self._pid, "tid": threading.get_ident(),
             "ts": self._t0, "dur": t1 - self._t0, "args": self.args})
-        return False
+        return super().__exit__(*exc)
 
 
 class SpanHandle:
@@ -214,13 +246,6 @@ class SpanHandle:
         """A complete event on *the calling thread*, linked via args.parent."""
         args["parent"] = self.id
         return _Span(self._buf, self._pid, name, self.cat, args)
-
-    def event(self, name: str, **args) -> None:
-        args["parent"] = self.id
-        self._buf.add({
-            "ph": "i", "name": name, "cat": self.cat, "s": "t",
-            "pid": self._pid, "tid": threading.get_ident(),
-            "ts": self._buf.now_us(), "args": args})
 
     def finish(self, **args) -> None:
         self._buf.add({
@@ -252,18 +277,10 @@ class Tracer:
 
     def span(self, name: str, cat: str = "ckpt", **args):
         if not self.state.enabled:
-            return _NULL_SPAN
+            return _AnnotatedSpan(name)
         return _Span(self.buffer, self.pid, name, cat, args)
 
     def begin(self, name: str, cat: str = "ckpt", **args):
         if not self.state.enabled:
             return _NULL_HANDLE
         return SpanHandle(self.buffer, self.pid, name, cat, args)
-
-    def instant(self, name: str, cat: str = "ckpt", **args) -> None:
-        if not self.state.enabled:
-            return
-        self.buffer.add({
-            "ph": "i", "name": name, "cat": cat, "s": "t",
-            "pid": self.pid, "tid": threading.get_ident(),
-            "ts": self.buffer.now_us(), "args": args})

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import loss_fn
+from repro.obs import scopes
 from repro.train.optim import OptConfig, apply_opt, clip_by_global_norm, init_opt
 
 
@@ -46,11 +47,12 @@ def make_train_step(cfg, oc: OptConfig = OptConfig(), *,
         else:
             loss, grads = grads_of(params, batch)
 
-        if oc.clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, oc.clip_norm)
-        else:
-            gnorm = jnp.zeros(())
-        params, opt_state = apply_opt(oc, params, grads, opt_state)
+        with jax.named_scope(scopes.OPTIMIZER):
+            if oc.clip_norm:
+                grads, gnorm = clip_by_global_norm(grads, oc.clip_norm)
+            else:
+                gnorm = jnp.zeros(())
+            params, opt_state = apply_opt(oc, params, grads, opt_state)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     return train_step
@@ -122,9 +124,10 @@ def make_compressed_dp_step(cfg, oc: OptConfig, mesh, *, frac=0.01,
             grads = jax.tree_util.tree_map(
                 lambda g: jax.lax.pmean(g, dp), grads)
         loss = jax.lax.pmean(loss, dp)
-        if oc.clip_norm:
-            grads, _ = clip_by_global_norm(grads, oc.clip_norm)
-        params, opt_state = apply_opt(oc, params, grads, opt_state)
+        with jax.named_scope(scopes.OPTIMIZER):
+            if oc.clip_norm:
+                grads, _ = clip_by_global_norm(grads, oc.clip_norm)
+            params, opt_state = apply_opt(oc, params, grads, opt_state)
         return params, opt_state, errors, loss
 
     rep = P()

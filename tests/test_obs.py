@@ -5,8 +5,10 @@ Covers the observability layer's load-bearing guarantees:
 * cross-thread span parenting and the Chrome trace-event schema
   (``b``/``e`` async pairs matched by ``(cat, id)``, stage sub-spans
   linked via ``args.parent``, per-(pid, tid) metadata);
-* the off-by-default fast path — disabled accessors return the shared
-  no-op singletons and record nothing;
+* the off-by-default fast path — disabled, nothing is buffered, nothing
+  registered and no ``telemetry.json`` written, while spans still reach a
+  profiler session, on the clock of the calls they enclose;
+* the scrutiny walk's phase times, published frozen by both managers;
 * drift-tracker bit-exactness: the device XOR/popcount path against a
   numpy ``packbits``/``unpackbits`` oracle, the host-mask path, and the
   identical-report zero-flip fast path;
@@ -17,6 +19,7 @@ Covers the observability layer's load-bearing guarantees:
   from ≥3 threads, and the report CLI renders it.
 """
 
+import glob
 import json
 import os
 import threading
@@ -29,6 +32,7 @@ import pytest
 from repro import obs
 from repro.checkpoint import CheckpointManager, Level
 from repro.checkpoint.coordinator import CoordinatedCheckpointManager
+from repro.core import participation
 from repro.core.criticality import CriticalityReport, LeafReport
 from repro.core.policy import LeafPolicy
 from repro.core.regions import RegionTable
@@ -38,7 +42,7 @@ from repro.obs import report as report_mod
 from repro.obs.drift import DriftTracker
 from repro.obs.metrics import (FrozenStats, MetricsRegistry, _NULL_METRIC,
                                freeze_stats)
-from repro.obs.trace import ObsState, _NULL_HANDLE, _NULL_SPAN
+from repro.obs.trace import ObsState, _NULL_HANDLE
 
 
 @pytest.fixture
@@ -112,24 +116,115 @@ def test_cross_thread_span_parenting_and_schema(obs_on):
 
 
 def test_disabled_path_is_noop_singletons():
-    """Disabled accessors hand back the shared null objects and leave the
-    buffer untouched — the hot-path cost is one branch."""
+    """Disabled, nothing is buffered and nothing registered: ``begin()``
+    and the metric accessors hand back the shared null objects, and a span
+    or a null handle's stage is a profiler annotation alone."""
     obs.reset()
     obs.disable()
     bundle = obs.get_obs()
     n0 = len(bundle.buffer)
-    assert bundle.tracer.span("x", a=1) is _NULL_SPAN
     assert bundle.tracer.begin("y") is _NULL_HANDLE
-    assert _NULL_HANDLE.stage("z") is _NULL_SPAN
     assert bundle.registry.counter("c") is _NULL_METRIC
     assert bundle.registry.gauge("g") is _NULL_METRIC
     assert bundle.registry.histogram("h") is _NULL_METRIC
-    with bundle.tracer.span("x"):
-        bundle.tracer.instant("tick")
-        bundle.registry.counter("c").inc(5)
+    with bundle.tracer.span("x", a=1) as sp:
+        sp.set(b=2)
+        with _NULL_HANDLE.stage("z"):
+            bundle.registry.counter("c").inc(5)
     assert len(bundle.buffer) == n0
     assert bundle.registry.to_dict() == {
         "counters": {}, "gauges": {}, "histograms": {}}
+
+
+def test_span_reaches_the_profiler_with_obs_off(tmp_path):
+    """With REPRO_OBS off a span still enters the profiler: a trace's host
+    plane holds it, around the dispatch of the jitted call it encloses
+    (one clock), and the Chrome buffer stays empty."""
+    from jax.profiler import ProfileData
+
+    obs.reset()
+    obs.disable()
+    bundle = obs.get_obs()
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((64, 64), jnp.float32)
+    f(x).block_until_ready()
+    n0 = len(bundle.buffer)
+    with jax.profiler.trace(str(tmp_path)):
+        with bundle.tracer.span("probe.span"):
+            f(x).block_until_ready()
+    assert len(bundle.buffer) == n0
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [(e.name, e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+    (span,) = [e for e in host if e[0] == "probe.span"]
+    calls = [e for e in host if e[0].startswith("PjitFunction")]
+    assert calls
+    for _, start, end in calls:
+        assert span[1] <= start <= end <= span[2]
+
+
+@pytest.mark.parametrize("on", [False, True])
+def test_telemetry_file_only_with_obs_on(tmp_path, on):
+    obs.reset()
+    (obs.enable if on else obs.disable)()
+    try:
+        state = {"w": jnp.arange(256, dtype=jnp.float32)}
+        with CheckpointManager([Level(str(tmp_path / "lv"))]) as mgr:
+            mgr.save(1, state, block=True)
+            mgr.wait()
+        assert os.path.exists(str(tmp_path / "lv" / "step_1" / "manifest.json"))
+        written = os.path.exists(
+            str(tmp_path / "lv" / "step_1" / "telemetry.json"))
+        assert written == on
+        assert (obs.get_obs().buffer.mark() > 0) == on      # events
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+def test_participation_records_its_phases():
+    state = {"w": jnp.arange(64, dtype=jnp.float32),
+             "step": jnp.asarray(1, jnp.int32)}
+    rep = participation(lambda s: {"y": s["w"][:32] * 2.0}, state)
+    stages = rep.stats["stages"]
+    assert set(stages) == {"trace_s", "forward_s", "taint_s", "tables_s"}
+    assert all(v >= 0 for v in stages.values())
+    assert rep["w"].mask.tolist() == [True] * 32 + [False] * 32
+
+
+@pytest.mark.parametrize("coordinated", [False, True])
+def test_manager_publishes_scrutiny_frozen(tmp_path, coordinated):
+    """The first save's scrutiny stats go through the registry as a frozen
+    ``"scrutiny"`` snapshot, as the save stats do; tracing stays off."""
+    obs.reset()
+    obs.disable()
+    state = {"w": jnp.arange(64, dtype=jnp.float32),
+             "step": jnp.asarray(1, jnp.int32)}
+
+    def scrutiny(s):
+        return participation(lambda t: {"y": t["w"][:32] * 2.0}, s)
+
+    levels = [Level(str(tmp_path / "lv"))]
+    if coordinated:
+        coll = FileCollective(str(tmp_path / "rdv"),
+                              ctx=ProcessContext(0, 1), timeout_s=30)
+        mgr = CoordinatedCheckpointManager(levels, collective=coll,
+                                           scrutiny_fn=scrutiny,
+                                           force_coordinated=True)
+    else:
+        mgr = CheckpointManager(levels, scrutiny_fn=scrutiny)
+    with mgr:
+        mgr.save(1, state, block=True)
+        live = mgr.last_scrutiny_stats
+        published = mgr.obs.registry.published["scrutiny"]
+    assert isinstance(published, FrozenStats)
+    assert isinstance(published["stages"], FrozenStats)
+    assert dict(published["stages"]) == dict(live["stages"])
+    with pytest.raises(TypeError):
+        published["stages"]["forward_s"] = 0.0
+    assert obs.get_obs().buffer.mark() == 0       # no event buffered
 
 
 def test_registry_thread_safety(obs_on):
