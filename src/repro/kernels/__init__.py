@@ -8,6 +8,7 @@ and ref.py (pure-jnp oracle used by the shape/dtype sweep tests).
                     matmul on the MXU (TPUs have no scatter unit) — the
                     paper's pack/unpack hot path at pod scale
 - flash_attention/  online-softmax attention (GQA + sliding window +
-                    logit softcap) with VMEM-resident (m, l, acc) carry
+                    logit softcap, masks from positions) with its own
+                    backward kernels; the TPU train and prefill path
 - lru_scan/         blocked diagonal linear recurrence (RG-LRU hot path)
 """

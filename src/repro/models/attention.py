@@ -1,12 +1,18 @@
 """Attention: GQA/MQA (+ sliding window, logit softcap, M-RoPE) and
 DeepSeek-style MLA with a compressed-latent KV cache for decode.
 
-Three execution paths:
-- ``_attend_full``: einsum attention for short sequences (smoke tests).
-- ``_attend_chunked``: online-softmax attention, scan over q/kv blocks —
-  the pure-jnp oracle of kernels/flash_attention and the path used for
-  32k+ sequences (keeps compile-time memory at block granularity).
-- kernels/flash_attention (Pallas, TPU): selected via ``set_attn_impl``.
+Three execution paths for a whole sequence (``_dispatch_attend``):
+- ``flash``: kernels/flash_attention, Pallas TPU kernels for the forward
+  and the backward, so no Tq x Tk tensor reaches HBM.  ``auto`` takes it
+  on a lone TPU chip wherever it serves the call: Tq == Tk, at least one
+  kernel block long, head dims multiples of 64, positions not M-RoPE.
+- ``full``: einsum attention over a materialized float32 bias; ``auto``
+  elsewhere while Tq * Tk is at most ``_FULL_THRESHOLD`` squared.
+- ``chunked``: online-softmax attention, scan over q/kv blocks, for the
+  longer sequences off the kernel (keeps compile-time memory at block
+  granularity).
+``set_attn_impl`` forces one ("pallas" is the kernel).  Decode
+(``attention_decode``, ``mla_decode``) attends with ``_attend_full``.
 
 Caches:
 - global layers: ``{"k": (B, S, K, D), "v": (B, S, K, D)}``
@@ -24,8 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.models.layers import (apply_mrope, apply_rope, dense_init,
                                  dtype_of, softcap)
+from repro.obs import get_obs
 
 _ATTN_IMPL = "auto"  # auto | full | chunked | pallas
 _CHUNK_Q = 512
@@ -170,21 +178,50 @@ def _attend_chunked(q, k, v, q_pos, k_pos, window, causal, scale, attn_cap,
     return o[:, :Tq].astype(q.dtype)
 
 
-def _dispatch_attend(q, k, v, q_pos, k_pos, window, causal, scale, attn_cap):
-    impl = _ATTN_IMPL
+def _flash_serves(q, k, v, mrope: bool) -> bool:
+    """The kernel serves this call: self-attention shaped, at least one
+    block long, MXU-sized head dims, 2-D positions."""
     Tq, Tk = q.shape[1], k.shape[1]
-    if impl == "pallas":
-        from repro.kernels.flash_attention import ops as fa_ops
-        return fa_ops.flash_attention(q, k, v, q_pos, k_pos, window=window,
-                                      causal=causal, scale=scale,
-                                      attn_cap=attn_cap)
+    return (Tq == Tk and Tq >= fa_ops.BLOCK and q.shape[-1] % 64 == 0
+            and v.shape[-1] % 64 == 0 and not mrope)
+
+
+def _on_one_tpu() -> bool:
+    """One TPU chip: the kernel is not partitioned, so a program that
+    spans chips keeps the einsum paths (XLA refuses to split it)."""
+    return jax.default_backend() == "tpu" and jax.device_count() == 1
+
+
+def _attend_impl(q, k, v, mrope: bool) -> str:
+    impl = _ATTN_IMPL
+    if impl == "pallas" or (impl == "auto" and _on_one_tpu()
+                            and _flash_serves(q, k, v, mrope)):
+        return "flash"
     # Perf iteration 1 (EXPERIMENTS.md §Perf/phi4): the "full" path
     # materializes a (B,Tq,Tk) fp32 bias whose partial computation over the
     # model axis costs a ~Tq·Tk·4B all-reduce per layer per pass; blockwise
     # iota masks in the chunked path eliminate it.  Threshold 2048² keeps
     # einsum attention only where the bias is genuinely small.
     thr = _FULL_THRESHOLD
+    Tq, Tk = q.shape[1], k.shape[1]
     if impl == "full" or (impl == "auto" and Tq * Tk <= thr * thr):
+        return "full"
+    return "chunked"
+
+
+def _dispatch_attend(q, k, v, q_pos, k_pos, window, causal, scale, attn_cap):
+    """Whole-sequence attention; positions (B, T), or (B, T, 3) under
+    M-RoPE, where the first (temporal) component masks."""
+    mrope = q_pos.ndim == 3
+    if mrope:
+        q_pos, k_pos = q_pos[..., 0], k_pos[..., 0]
+    impl = _attend_impl(q, k, v, mrope)
+    get_obs().registry.counter(f"attention.impl.{impl}").inc()
+    if impl == "flash":
+        return fa_ops.flash_attention(q, k, v, q_pos, k_pos, window=window,
+                                      causal=causal, scale=scale,
+                                      attn_cap=attn_cap)
+    if impl == "full":
         bias = _mask_bias(q_pos, k_pos, window, causal)
         return _attend_full(q, k, v, bias, scale, attn_cap)
     return _attend_chunked(q, k, v, q_pos, k_pos, window, causal, scale, attn_cap)
@@ -231,8 +268,7 @@ def attention_train(cfg, p, x, positions, *, window=None, causal=True):
     """Full-sequence self-attention (training / prefill without cache)."""
     q, k, v = _project_qkv(cfg, p, x, positions)
     scale = cfg.resolved_head_dim ** -0.5
-    pos = positions[..., 0] if positions.ndim == 3 else positions
-    o = _dispatch_attend(q, k, v, pos, pos, window, causal, scale,
+    o = _dispatch_attend(q, k, v, positions, positions, window, causal, scale,
                          cfg.attn_softcap)
     B, T = x.shape[:2]
     return o.reshape(B, T, -1) @ p["wo"].astype(x.dtype)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import recurrent as rec
@@ -437,15 +438,19 @@ def _run_segments(cfg, params, x, positions, enc_out=None, enc_positions=None,
             # Perf iteration 2 (EXPERIMENTS.md §Perf/phi4): saving matmul
             # outputs means the backward pass does not replay the forward's
             # row-parallel all-reduces (TP collectives) or the matmul FLOPs;
-            # only cheap elementwise work is recomputed.
-            policy = (None if _REMAT_POLICY == "full" else
-                      jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            # only cheap elementwise work is recomputed.  The flash kernel's
+            # output and log-sum-exp are saved too, so the replay does not
+            # rerun the forward kernel.
+            policy = (None if _REMAT_POLICY == "full" else _DOTS_POLICY)
             body = jax.checkpoint(body, prevent_cse=False, policy=policy)
         (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), stacked)
     return x, aux_total
 
 
 _REMAT_POLICY = "dots"  # dots (optimized) | full (baseline everything-remat)
+_DOTS_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(*fa_ops.RESIDUALS))
 
 
 def set_remat_policy(mode: str) -> None:
@@ -534,9 +539,8 @@ def _prefill_block(cfg, kind, p, x, positions, max_len, enc_out, enc_pos):
             h2, cache = _mla_prefill(cfg, p["mixer"], h, positions, max_len)
         else:
             q, k, v = attn._project_qkv(cfg, p["mixer"], h, positions)
-            pos = positions[..., 0] if positions.ndim == 3 else positions
-            o = attn._dispatch_attend(q, k, v, pos, pos, window, True,
-                                      cfg.resolved_head_dim ** -0.5,
+            o = attn._dispatch_attend(q, k, v, positions, positions, window,
+                                      True, cfg.resolved_head_dim ** -0.5,
                                       cfg.attn_softcap)
             h2 = o.reshape(B, T, -1) @ p["mixer"]["wo"].astype(h.dtype)
             S = min(window, max_len) if window else max_len

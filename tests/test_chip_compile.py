@@ -1,4 +1,5 @@
-"""The save/restore kernels compile for a TPU v5e at real widths.
+"""The save/restore and attention kernels compile for a TPU v5e at real
+widths.
 
 Nothing runs here: each kernel is lowered and compiled for one chip of a
 described (not attached) ``v5e:2x2`` topology (or, for operands that span
@@ -6,8 +7,11 @@ chips, per chip over all four under ``shard_map``), so what the chip's Mosaic
 compiler refuses — unaligned blocks, primitives it cannot lower, more
 VMEM than a kernel may use — fails on a CPU-only machine.  The widths are
 xlstm-125m's embedding leaf (50304 x 768 = 38,633,472 elements), the
-largest leaf a full-width save packs.  The ``kernel`` functions are
-called directly, since ``ops`` dispatches on the default backend (CPU).
+largest leaf a full-width save packs; the attention kernels' are
+SmolLM2-135M's train step (batch 2 x 2,048, 9 query and 3 key/value heads
+of 64) and DeepSeek-V3's MLA head dims (192 for q and k, 128 for v).  The
+``kernel`` functions are called directly, since ``ops`` dispatches on the
+default backend (CPU).
 """
 
 from __future__ import annotations
@@ -20,6 +24,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.kernels.flash_attention import kernel as FA
 from repro.kernels.mask_pack import kernel as K
 from repro.kernels.mask_pack.ops import DELTA_CHUNK_BYTES, per_device_fn
 
@@ -122,3 +127,32 @@ def test_per_device_delta_compiles(four_chips, n):
                        four_chips, 2)
     _compile(fn, NamedSharding(four_chips, P("d")), ((n,), jnp.int32),
              ((n,), jnp.int32))
+
+
+#: (B, T, H, K, D, Dv): SmolLM2-135M's train step; MLA's head dims
+FA_WIDTHS = {"smollm2": (2, 2048, 9, 3, 64, 64),
+             "mla": (1, 2048, 16, 16, 192, 128)}
+
+
+@pytest.mark.parametrize("widths", sorted(FA_WIDTHS))
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dkv", "bwd_dq"])
+def test_flash_attention_compiles(one_chip, kernel, widths):
+    """``flash_attention_<kernel>`` at the block size the model path uses,
+    causal, bfloat16 operands as in the train step."""
+    from repro.kernels.flash_attention.ops import BLOCK
+
+    B, T, H, Kh, D, Dv = FA_WIDTHS[widths]
+    G, bf = H // Kh, jnp.bfloat16
+    spec = FA.Spec(causal=True, window=None, attn_cap=None, block_q=BLOCK,
+                   block_k=BLOCK, tk=T)
+    qkv = [((B, Kh, G, T, D), bf), ((B, Kh, T, D), bf), ((B, Kh, T, Dv), bf),
+           ((B, T), jnp.int32)]
+    rows = [((B, Kh, G, T), jnp.float32)] * 2 + [((B, Kh, G, T, Dv), bf)]
+    if kernel == "fwd":
+        _compile(lambda q, k, v, p: FA.flash_attention_fwd(q, k, v, p, p, spec),
+                 one_chip, *qkv)
+    else:
+        fn = getattr(FA, "flash_attention_" + kernel)
+        _compile(lambda q, k, v, p, lse, dl, do: fn(q, k, v, p, p, lse, dl,
+                                                     do, spec),
+                 one_chip, *qkv, *rows)

@@ -6,12 +6,14 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.flash_attention.kernel import flash_attention_kernel
+from repro.kernels.flash_attention import kernel as FA
 from repro.kernels.lru_scan.kernel import lru_scan_kernel
 from repro.kernels.mask_pack import kernel as K
 
 N = 40 * 512
 F32, I8, I32 = jnp.float32, jnp.int8, jnp.int32
+FA_SPEC = FA.Spec(causal=True, window=None, attn_cap=None, block_q=128,
+                  block_k=128, tk=256)
 
 
 def _kernel_names(fn, *shapes):
@@ -34,11 +36,22 @@ def _kernel_names(fn, *shapes):
      [((40 * K.BITPACK_BLOCK,), F32)]),
     ("mask_pack_delta", lambda c, b: K.delta_blocks_kernel(c, b, 16),
      [((N,), I32), ((N,), I32)]),
-    ("flash_attention",
-     lambda q, k, v: flash_attention_kernel(q, k, v, scale=0.125, causal=True,
-                                            window=None, attn_cap=None),
-     [((1, 256, 4, 64), F32), ((1, 256, 2, 64), F32),
-      ((1, 256, 2, 64), F32)]),
+    ("flash_attention_fwd",
+     lambda q, k, v, p: FA.flash_attention_fwd(q, k, v, p, p, FA_SPEC),
+     [((1, 2, 3, 256, 64), F32), ((1, 2, 256, 64), F32),
+      ((1, 2, 256, 64), F32), ((1, 256), I32)]),
+    ("flash_attention_bwd_dkv",
+     lambda q, k, v, p, l, do: FA.flash_attention_bwd_dkv(
+         q, k, v, p, p, l, l, do, FA_SPEC),
+     [((1, 2, 3, 256, 64), F32), ((1, 2, 256, 64), F32),
+      ((1, 2, 256, 64), F32), ((1, 256), I32), ((1, 2, 3, 256), F32),
+      ((1, 2, 3, 256, 64), F32)]),
+    ("flash_attention_bwd_dq",
+     lambda q, k, v, p, l, do: FA.flash_attention_bwd_dq(
+         q, k, v, p, p, l, l, do, FA_SPEC),
+     [((1, 2, 3, 256, 64), F32), ((1, 2, 256, 64), F32),
+      ((1, 2, 256, 64), F32), ((1, 256), I32), ((1, 2, 3, 256), F32),
+      ((1, 2, 3, 256, 64), F32)]),
     ("lru_scan", lambda a, b, h: lru_scan_kernel(a, b, h),
      [((1, 256, 128), F32), ((1, 256, 128), F32), ((1, 128), F32)]),
 ])

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import ops as fa_ops
-from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.lru_scan.kernel import lru_scan_kernel
 from repro.kernels.lru_scan.ref import lru_scan_ref
@@ -22,7 +21,7 @@ from repro.kernels.mask_pack import ops as mp_ops
 # --------------------------------------------------------------------------
 
 FA_CASES = [
-    # (B, T, H, K, D, window, causal, cap, dtype)
+    # (B, T, H, K, D, window, causal, cap, dtype[, Dv, positions])
     (1, 128, 4, 4, 64, None, True, None, jnp.float32),
     (2, 256, 8, 2, 64, None, True, None, jnp.float32),     # GQA 4:1
     (1, 256, 4, 1, 128, None, True, None, jnp.float32),    # MQA
@@ -31,24 +30,72 @@ FA_CASES = [
     (1, 256, 4, 2, 64, 128, True, 50.0, jnp.bfloat16),     # all combined bf16
     (2, 128, 2, 2, 256, None, True, None, jnp.float32),    # gemma-7b head_dim
     (1, 128, 4, 4, 64, None, False, None, jnp.float32),    # non-causal (enc)
+    (2, 256, 6, 2, 64, None, True, None, jnp.float32),     # GQA 3:1 (SmolLM2)
+    (1, 256, 6, 2, 64, None, False, None, jnp.float32),    # GQA 3:1 non-causal
+    (1, 384, 6, 2, 64, 128, True, None, jnp.float32),      # window skips blocks
+    (1, 256, 6, 2, 64, None, True, 30.0, jnp.float32),     # softcap, GQA 3:1
+    (1, 256, 4, 2, 64, None, True, None, jnp.float32, 32),  # Dv != D (MLA)
+    (1, 200, 6, 2, 64, None, True, None, jnp.float32),     # T padded to block
+    (1, 200, 4, 4, 64, None, False, None, jnp.float32),    # padded, non-causal
+    (2, 256, 6, 2, 64, None, True, None, jnp.float32, None, "offset"),
+    (1, 256, 6, 2, 64, 64, True, None, jnp.float32, None, "repeat"),
+    (1, 256, 6, 2, 64, 128, True, 50.0, jnp.bfloat16),     # combined, GQA 3:1
 ]
+
+#: the features of FA_CASES, differentiated: GQA 3:1, causal and not,
+#: window, softcap, Dv != D, padding, offset and repeated positions
+FA_GRAD_CASES = [FA_CASES[i] for i in (8, 9, 10, 11, 12, 13, 14, 15, 16)]
+
+
+def _fa_inputs(case, seed=0):
+    B, T, H, K, D, window, causal, cap, dt = case[:9]
+    Dv = (case[9:10] or [None])[0] or D
+    positions = (case[10:11] or ["arange"])[0]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32).astype(dt)
+    k = jax.random.normal(ks[1], (B, T, K, D), jnp.float32).astype(dt)
+    v = jax.random.normal(ks[2], (B, T, K, Dv), jnp.float32).astype(dt)
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    if positions == "offset":       # each row shifted by its own offset
+        pos = pos + jnp.arange(B, dtype=jnp.int32)[:, None] * 1000 + 7
+    elif positions == "repeat":     # every position twice
+        pos = pos // 2
+    kw = dict(window=window, causal=causal, scale=D ** -0.5, attn_cap=cap)
+    return (q, k, v, pos), kw
+
+
+def _flash(q, k, v, q_pos, k_pos, **kw):
+    # 128-row blocks: the cases span several, so pairs are skipped/masked
+    return fa_ops.flash_attention(q, k, v, q_pos, k_pos, interpret=True,
+                                  block=128, **kw)
 
 
 @pytest.mark.parametrize("case", FA_CASES)
 def test_flash_attention_vs_ref(case):
-    B, T, H, K, D, window, causal, cap, dt = case
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32).astype(dt)
-    k = jax.random.normal(ks[1], (B, T, K, D), jnp.float32).astype(dt)
-    v = jax.random.normal(ks[2], (B, T, K, D), jnp.float32).astype(dt)
-    out = flash_attention_kernel(q, k, v, scale=D ** -0.5, causal=causal,
-                                 window=window, attn_cap=cap, interpret=True)
-    ref = flash_attention_ref(q, k, v, window=window, causal=causal,
-                              scale=D ** -0.5, attn_cap=cap)
-    tol = 2e-2 if dt == jnp.bfloat16 else 2e-5
+    (q, k, v, pos), kw = _fa_inputs(case)
+    out = _flash(q, k, v, pos, pos, **kw)
+    ref = flash_attention_ref(q, k, v, pos, pos, **kw)
+    tol = 2e-2 if q.dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("case", FA_GRAD_CASES)
+def test_flash_attention_grad_vs_ref(case):
+    """jax.grad through the kernels' custom VJP (the backward kernels)
+    against jax.grad of the plain attention."""
+    (q, k, v, pos), kw = _fa_inputs(case)
+    w = jax.random.normal(jax.random.PRNGKey(9), q.shape[:3] + v.shape[-1:])
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, pos, pos, **kw) * w)
+
+    got = jax.grad(loss(_flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(flash_attention_ref), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=5e-5, rtol=5e-5)
 
 
 def test_flash_attention_ops_padding():
